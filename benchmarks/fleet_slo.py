@@ -38,17 +38,17 @@ REF_MSG = 1024
 #: heterogeneous accelerator complements, cycled across the fleet (the
 #: first accel is shared so the reference flow is comparable server-to-
 #: server; the rest make the accel tables ragged)
-_COMPLEMENTS = (
+COMPLEMENTS = (
     ["synthetic50"],
     ["synthetic50", "aes256"],
     ["synthetic50", "aes256", "ipsec32"],
 )
 
 
-def _fleet_specs(b: int) -> list[FlowSpec]:
+def fleet_specs(b: int) -> list[FlowSpec]:
     """Server b's flows: the shared reference flow plus 0-2 extra flows on
     the server's extra accelerators (ragged flow counts)."""
-    names = _COMPLEMENTS[b % len(_COMPLEMENTS)]
+    names = COMPLEMENTS[b % len(COMPLEMENTS)]
     specs = [FlowSpec(0, 0, Path.FUNCTION_CALL, 0,
                       TrafficPattern(REF_MSG, load=0.4, process="poisson"),
                       SLO.gbps(REF_SLO_GBPS))]
@@ -63,16 +63,16 @@ def _fleet_specs(b: int) -> list[FlowSpec]:
 def _build_fleet(n_servers: int, profile: ProfileTable
                  ) -> list[ArcusRuntime]:
     rts = [ArcusRuntime([CATALOG[n]
-                         for n in _COMPLEMENTS[b % len(_COMPLEMENTS)]],
+                         for n in COMPLEMENTS[b % len(COMPLEMENTS)]],
                         profile_table=profile)
            for b in range(n_servers)]
-    specs = [_fleet_specs(b) for b in range(n_servers)]
+    specs = [fleet_specs(b) for b in range(n_servers)]
     accepted = register_fleet(rts, specs)
     assert all(all(a) for a in accepted), "fleet admission rejected a flow"
     return rts
 
 
-def _refs(rts) -> list[dict[int, float]]:
+def fleet_refs(rts) -> list[dict[int, float]]:
     return [{i: 32.0 for i in range(len(rt.table))} for rt in rts]
 
 
@@ -102,7 +102,7 @@ def run(quick: bool = False) -> list[Row]:
         with Timer() as t:
             results, reports = run_managed_batch(
                 rts, total_ticks=total, window_ticks=window,
-                seeds=seeds, load_ref_gbps=_refs(rts))
+                seeds=seeds, load_ref_gbps=fleet_refs(rts))
         info = engine.cache_info()
         # the whole heterogeneous fleet (mixed flow counts, mixed accel
         # counts, per-server registers) is ONE compiled engine entry
@@ -132,14 +132,14 @@ def run(quick: bool = False) -> list[Row]:
     with Timer() as t_ser:
         serial = [rt.run_managed(total_ticks=total, window_ticks=window,
                                  seed=seeds[b],
-                                 load_ref_gbps=_refs(rts_serial)[b])
+                                 load_ref_gbps=fleet_refs(rts_serial)[b])
                   for b, rt in enumerate(rts_serial)]
     rts_batch = _build_fleet(B, profile)
     engine.cache_clear()
     with Timer() as t_bat:
         results, reports = run_managed_batch(
             rts_batch, total_ticks=total, window_ticks=window,
-            seeds=seeds, load_ref_gbps=_refs(rts_batch))
+            seeds=seeds, load_ref_gbps=fleet_refs(rts_batch))
     match = all(
         np.array_equal(np.asarray(s.counters[k]), np.asarray(r.counters[k]))
         for (s, _), r in zip(serial, results)

@@ -16,6 +16,8 @@ import sys
 import time
 import traceback
 
+from repro import compile_cache
+
 MODULES = [
     "sim_perf",                  # engine compile-cache / batching speed
     "fleet_slo",                 # fleet-scale batched control plane
@@ -44,6 +46,7 @@ def main() -> None:
                     help="shorter sims (CI-scale)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    compile_cache.configure()
 
     print("name,us_per_call,derived")
     failures = 0

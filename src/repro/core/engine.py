@@ -748,18 +748,26 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
         cacc = fl_accel[order]
         spend = jnp.where((cdir != 2) & valid,
                           csz.astype(jnp.float32) + ovh, 0.0)
+        # Prefix sums over the candidates.  The f32 ones are matmuls at
+        # HIGHEST precision: at DEFAULT, XLA:TPU runs an f32 dot as one
+        # bf16 pass, which rounds byte sums to 8 mantissa bits (XLA:CPU
+        # ignores the precision field, so CPU results are unchanged).  The
+        # int32 ones are masked sums — exact on every backend.
+        hi = jax.lax.Precision.HIGHEST
         lt_i = jnp.tril(jnp.ones((K, K), jnp.int32), -1)   # [j, i]: i < j
         lt_f = lt_i.astype(jnp.float32)
         same_dir = (d01[None, :] == d01[:, None])
-        cum_spend = (lt_f * same_dir.astype(jnp.float32)) @ spend
+        cum_spend = jnp.dot(lt_f * same_dir.astype(jnp.float32), spend,
+                            precision=hi)
         bud_ok = (cdir == 2) | (budget[d01] - cum_spend > 0.0)
         same_acc = (cacc[None, :] == cacc[:, None]).astype(jnp.int32)
-        cnt_before = (lt_i * same_acc) @ vi
-        byt_before = (lt_i * same_acc) @ jnp.where(valid, csz, 0)
+        cnt_before = jnp.sum(lt_i * same_acc * vi[None, :], axis=1)
+        byt_before = jnp.sum(lt_i * same_acc
+                             * jnp.where(valid, csz, 0)[None, :], axis=1)
         aq_ok = ((carry["aq_cnt"][cacc] + cnt_before < cfg.aq_len)
                  & (carry["aq_bytes"][cacc] + byt_before + csz
                     <= cfg.aq_byte_cap))
-        idx_before = lt_i @ vi
+        idx_before = jnp.sum(lt_i * vi[None, :], axis=1)
         cred_ok = carry["credits_used"] + idx_before < credits
         ok_all = jnp.all(~valid | (bud_ok & aq_ok & cred_ok))
         if R_res:
@@ -769,7 +777,7 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
             c_any = res_w_any[:, order]                         # [R, K]
             c_rspend = (res_w_in[:, order]
                         * jnp.where(valid, csz, 0).astype(jnp.float32))
-            cum_res = c_rspend @ lt_f.T                         # [R, K]
+            cum_res = jnp.dot(c_rspend, lt_f.T, precision=hi)   # [R, K]
             res_ok_c = jnp.all(
                 (~c_any) | (res_bud[:, None] - cum_res > 0.0), axis=0)
             ok_all = ok_all & jnp.all(~valid | res_ok_c)
@@ -856,10 +864,14 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
         end = jnp.maximum(lanes_a[lane], jnp.float32(now)) + svc
         c["lanes"] = c["lanes"].at[a, lane].set(
             jnp.where(ok, end, lanes_a[lane]))
-        c["aq_head"] = c["aq_head"].at[a].add(ok.astype(jnp.int32)) \
-            % cfg.aq_len
-        c["aq_cnt"] = c["aq_cnt"].at[a].add(-ok.astype(jnp.int32))
-        c["aq_bytes"] = c["aq_bytes"].at[a].add(jnp.where(ok, -sz, 0))
+        # the pop is a masked select over the accel axis, not a scatter-add
+        # at [a]: unrolled, `a` is a constant, and XLA:TPU miscompiled those
+        # constant-index scatter-adds in the unbatched engine (idle accels'
+        # queues were popped too).  Integer selects: the same bits anywhere.
+        pop = (jnp.arange(A, dtype=jnp.int32) == a) & ok
+        c["aq_head"] = (c["aq_head"] + jnp.where(pop, 1, 0)) % cfg.aq_len
+        c["aq_cnt"] = c["aq_cnt"] - jnp.where(pop, 1, 0)
+        c["aq_bytes"] = c["aq_bytes"] - jnp.where(pop, sz, 0)
         # host-processing delay (software-mediated shaping only; the LCG
         # advances once per *active-accelerator* iteration whenever shaping
         # is software, busy or idle, exactly like the closed-form batch
@@ -956,7 +968,8 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
             Mt = A * Ks
             lt = jnp.tril(jnp.ones((Mt, Mt), jnp.int32), -1)
             same_d = (d[None, :] == d[:, None]).astype(jnp.int32)
-            rank = (lt * same_d) @ okf.astype(jnp.int32)
+            rank = jnp.sum(lt * same_d * okf.astype(jnp.int32)[None, :],
+                           axis=1)
             okq = okf & (c["eq_cnt"][d] + rank < cfg.eq_len)
             eslot = (c["eq_head"][d] + c["eq_cnt"][d] + rank) % cfg.eq_len
             drow = jnp.where(okq, d, 3)           # OOB rows are dropped
@@ -1144,9 +1157,9 @@ def cache_info() -> dict[str, int]:
 
     ``traces`` counts actual jit-cache entries across all cached engines —
     a steady value across repeated ``simulate()`` / ``run_managed`` windows
-    proves zero recompiles.  ``_cache_size`` is a private jit attribute
-    (present in the pinned jax; see requirements-dev.txt) — if a future
-    jax drops it we degrade to one trace per entry rather than raising."""
+    proves zero recompiles.  ``_cache_size`` is a private attribute of
+    jit-wrapped functions (present in the jax pinned by
+    requirements-dev.txt); without it each entry counts as one trace."""
     return {"entries": len(_RUN_CACHE),
             "traces": sum(getattr(f, "_cache_size", lambda: 1)()
                           for f in _RUN_CACHE.values())}

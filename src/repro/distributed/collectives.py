@@ -15,7 +15,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def make_seq_sharded_decode_attn(mesh: Mesh, axis: str = "data",
@@ -72,12 +71,12 @@ def make_seq_sharded_decode_attn(mesh: Mesh, axis: str = "data",
     def fn(q, k, v, lengths, *, window: int = 0):
         f = functools.partial(local_fn, window=window)
         dsp = d_axis  # None -> replicated D
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(bp, None, dsp), P(bp, axis, None, dsp),
                       P(bp, axis, None, dsp), P(bp)),
             out_specs=P(bp, None, dsp),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v, lengths)
 
     return fn
@@ -108,13 +107,13 @@ def make_seq_sharded_cache_update(mesh: Mesh, axis: str = "data",
 
     def fn(cache_k, cache_v, k_new, v_new, slot):
         dsp = d_axis
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(bp, axis, None, dsp), P(bp, axis, None, dsp),
                       P(bp, None, dsp), P(bp, None, dsp), P(bp)),
             out_specs=(P(bp, axis, None, dsp),
                        P(bp, axis, None, dsp)),
-            check_rep=False,
+            check_vma=False,
         )(cache_k, cache_v, k_new, v_new, slot)
 
     return fn

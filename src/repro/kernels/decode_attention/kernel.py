@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def decode_attention_grouped(q, k, v, lengths, *, s_block: int = 512,
             pltpu.VMEM((G, 128), jnp.float32),
             pltpu.VMEM((G, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k, v)

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -115,7 +114,7 @@ def flash_prefill_flat(q, k, v, *, bq: int = 128, bk: int = 128,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, s_ref,
@@ -101,7 +100,7 @@ def ssd_scan_headmajor(x, a, B, C, *, chunk: int = 128,
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a, B, C)

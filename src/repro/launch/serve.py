@@ -14,6 +14,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_reduced_config
 from repro.core.flow import SLO
 from repro.models import transformer as T
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--unshaped", action="store_true",
                     help="FCFS baseline instead of Arcus shaping")
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = get_reduced_config(args.arch)
     params, _ = T.init_model(0, cfg)

@@ -103,9 +103,12 @@ def init_model(key_or_seed, cfg: ArchConfig):
 
 
 def init_model_params_only(seed, cfg: ArchConfig, dtype=jnp.bfloat16):
-    """Params cast to `dtype` (axes discarded) — eval_shape friendly."""
-    p, _ = init_model(seed, cfg)
-    return nn.tree_cast(p, dtype)
+    """Params cast to `dtype` (axes discarded) — eval_shape friendly.
+
+    Built as one compiled program, so each float32 draw is cast as it is
+    made: the float32 tree never lives on the device whole (at gemma3-12b
+    width it alone would take most of a 16 GB chip)."""
+    return jax.jit(lambda: nn.tree_cast(init_model(seed, cfg)[0], dtype))()
 
 
 def init_model_axes(cfg: ArchConfig):

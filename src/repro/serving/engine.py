@@ -35,26 +35,29 @@ def _scatter_cache(batch_cache, one_cache, slot: int):
     return {"blocks": new_blocks, "tail": new_tail}
 
 
+#: compiled steps shared by every engine of one ArchConfig (the config is
+#: a static argument), so a second engine over the same model recompiles
+#: nothing
+_decode = jax.jit(T.decode_step, static_argnums=(1,))
+_prefill = jax.jit(T.prefill, static_argnums=(1,))
+
+
 @dataclasses.dataclass
 class ServingEngine:
     cfg: ArchConfig
     params: Any
     max_batch: int
     max_len: int
-    cache_dtype: Any = jnp.float32
     greedy: bool = True
 
     def __post_init__(self):
+        # the KV cache is kept in the model's compute dtype
+        self.cache_dtype = jnp.dtype(self.cfg.dtype)
         self.cache = T.init_cache(self.cfg, self.max_batch, self.max_len,
                                   self.cache_dtype)
         self.lengths = np.zeros(self.max_batch, np.int32)
         self.active = np.zeros(self.max_batch, bool)
         self.requests: dict[int, Request] = {}
-        self._decode = jax.jit(
-            lambda p, tok, ln, cache: T.decode_step(p, self.cfg, tok, ln,
-                                                    cache))
-        self._prefill = jax.jit(
-            lambda p, tok, cache, fe: T.prefill(p, self.cfg, tok, cache, fe))
 
     # ------------------------------------------------------------------
     def free_slots(self) -> list[int]:
@@ -65,7 +68,7 @@ class ServingEngine:
         slot = self.free_slots()[0]
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
         one = T.init_cache(self.cfg, 1, self.max_len, self.cache_dtype)
-        logits, one, _ = self._prefill(self.params, tokens, one, frontend)
+        logits, one, _ = _prefill(self.params, self.cfg, tokens, one, frontend)
         tok = int(jnp.argmax(logits[0]))
         self.cache = _scatter_cache(self.cache, one, slot)
         self.lengths[slot] = len(req.prompt)
@@ -85,8 +88,8 @@ class ServingEngine:
         for r in self.requests.values():
             if r.slot >= 0 and r.generated:
                 last[r.slot, 0] = r.generated[-1]
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(last),
+        logits, self.cache = _decode(
+            self.params, self.cfg, jnp.asarray(last),
             jnp.asarray(self.lengths), self.cache)
         toks = np.asarray(jnp.argmax(logits, -1))
         out = {}
