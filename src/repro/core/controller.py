@@ -385,32 +385,34 @@ class FleetController:
         the warmed per-server path — accept/reject decisions identical to
         serial registration.  An empty per-server list is valid; a
         length mismatch is rejected before any work."""
-        runtimes = self.runtimes
-        if len(fleet_specs) != len(runtimes):
-            raise ValueError(
-                f"fleet_specs must have one spec list per server "
-                f"(got {len(fleet_specs)} lists for {len(runtimes)} "
-                "servers)")
-        results: list[list[bool]] = [[] for _ in runtimes]
-        rounds = max((len(s) for s in fleet_specs), default=0)
-        for r in range(rounds):
-            jobs = []
-            for b, rt in enumerate(runtimes):
-                if r >= len(fleet_specs[b]):
-                    continue
-                accel, _peers, ctx = rt._admission_context(fleet_specs[b][r])
-                jobs.append((rt.profile, accel, ctx))
-            profile_contexts_multi(jobs)
-            for b, rt in enumerate(runtimes):
-                if r < len(fleet_specs[b]):
-                    ok = rt.register(fleet_specs[b][r])
-                    results[b].append(ok)
-                    if ok:
-                        self._assign_lane(b, fleet_specs[b][r].flow_id)
-                        self.stats["admitted"] += 1
-                    else:
-                        self.stats["rejected"] += 1
-        return results
+        with jax.profiler.TraceAnnotation("arcus.fleet.admit"):
+            runtimes = self.runtimes
+            if len(fleet_specs) != len(runtimes):
+                raise ValueError(
+                    f"fleet_specs must have one spec list per server "
+                    f"(got {len(fleet_specs)} lists for {len(runtimes)} "
+                    "servers)")
+            results: list[list[bool]] = [[] for _ in runtimes]
+            rounds = max((len(s) for s in fleet_specs), default=0)
+            for r in range(rounds):
+                jobs = []
+                for b, rt in enumerate(runtimes):
+                    if r >= len(fleet_specs[b]):
+                        continue
+                    accel, _peers, ctx = rt._admission_context(
+                        fleet_specs[b][r])
+                    jobs.append((rt.profile, accel, ctx))
+                profile_contexts_multi(jobs)
+                for b, rt in enumerate(runtimes):
+                    if r < len(fleet_specs[b]):
+                        ok = rt.register(fleet_specs[b][r])
+                        results[b].append(ok)
+                        if ok:
+                            self._assign_lane(b, fleet_specs[b][r].flow_id)
+                            self.stats["admitted"] += 1
+                        else:
+                            self.stats["rejected"] += 1
+            return results
 
     # ------------------------------------------------------------------
     # Departure + rebalancing
@@ -737,6 +739,12 @@ class FleetController:
         re-pack; servers whose policies held steady keep the
         no-register-rewrite resume path.
 
+        Each step of the loop runs under a profiler span named for its
+        layer (``arcus.fleet.event``, ``.lanes``, ``.poll``, ``.pass``,
+        ``.control``, ``.collect``; the engine call adds
+        ``arcus.engine.prepare`` and ``.dispatch``), so a trace of a run
+        splits the host time between windows.
+
         Returns ``(results, reports)``: one last-window ``SimResult`` per
         server (rows in lane order — see ``lane_map``; with no holes that
         is sorted-flow-id order; a mid-run arrival occupies a fresh lane
@@ -852,9 +860,10 @@ class FleetController:
         try:
             for w, (t0, wcfg) in enumerate(windows):
                 for ei, ev in ev_by_w.get(w, ()):
-                    arr_t, arr_sz, carry, touched, spliced = \
-                        self._apply_event(ev, ei, t0, full_cfg, seeds_l,
-                                          arr_t, arr_sz, carry, width)
+                    with jax.profiler.TraceAnnotation("arcus.fleet.event"):
+                        arr_t, arr_sz, carry, touched, spliced = \
+                            self._apply_event(ev, ei, t0, full_cfg, seeds_l,
+                                              arr_t, arr_sz, carry, width)
                     for b in touched:
                         dirty[b] = True
                     # baseline reset: a recycled lane's device counters
@@ -869,18 +878,22 @@ class FleetController:
                                 if not v.flags.writeable:
                                     v = prev[k] = v.copy()
                                 v[bb, ll] = 0
-                for b in range(B):
-                    if tbss[b] is None or dirty[b]:
-                        flowsets[b], masks[b], tbss[b] = \
-                            self._build_lane_args(b, width)
+                rebuild = [b for b in range(B) if tbss[b] is None or dirty[b]]
+                if rebuild:
+                    with jax.profiler.TraceAnnotation("arcus.fleet.lanes"):
+                        for b in rebuild:
+                            flowsets[b], masks[b], tbss[b] = \
+                                self._build_lane_args(b, width)
                 writes = tbss if (carry is None or any(dirty)
                                   or _force_rebuild) else None
                 carry = engine.run_window_batch(
                     flowsets, atabs, links, wcfg, writes, arr_t, arr_sz,
                     t0_ticks=t0, carry=carry, fl_masks=masks)
-                host = jax.device_get({k: carry[k]
-                                       for k in telemetry.FLEET_POLL_KEYS})
-                prev = self._fleet_pass(host, prev, wcfg, t0, reports)
+                with jax.profiler.TraceAnnotation("arcus.fleet.poll"):
+                    host = jax.device_get(
+                        {k: carry[k] for k in telemetry.FLEET_POLL_KEYS})
+                with jax.profiler.TraceAnnotation("arcus.fleet.pass"):
+                    prev = self._fleet_pass(host, prev, wcfg, t0, reports)
                 dirty = [_force_rebuild
                          or bool(reports[b][-1].reconfigured
                                  or reports[b][-1].path_changes)
@@ -890,18 +903,20 @@ class FleetController:
                     # last window has no next window to actuate into; not
                     # deciding there keeps post-run control state — and
                     # StaticHold runs entirely — bitwise)
-                    for b, changed in enumerate(
-                            self._control_decide(w, wcfg, reports)):
-                        if changed:
+                    with jax.profiler.TraceAnnotation("arcus.fleet.control"):
+                        changed = self._control_decide(w, wcfg, reports)
+                    for b, ch in enumerate(changed):
+                        if ch:
                             dirty[b] = True
         finally:
             self._in_run = False
-        host = jax.device_get({k: carry[k] for k in sim._RESULT_KEYS})
-        t0_last, wcfg_last = windows[-1]
-        results = []
-        for b in range(B):
-            el = {k: v[b] for k, v in host.items()}
-            for k in sim._PER_FLOW_KEYS:
-                el[k] = el[k][:len(self._lanes[b])]
-            results.append(sim._collect_result(el, wcfg_last, t0_last))
+        with jax.profiler.TraceAnnotation("arcus.fleet.collect"):
+            host = jax.device_get({k: carry[k] for k in sim._RESULT_KEYS})
+            t0_last, wcfg_last = windows[-1]
+            results = []
+            for b in range(B):
+                el = {k: v[b] for k, v in host.items()}
+                for k in sim._PER_FLOW_KEYS:
+                    el[k] = el[k][:len(self._lanes[b])]
+                results.append(sim._collect_result(el, wcfg_last, t0_last))
         return results, reports

@@ -562,566 +562,573 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
     now_end = now + cfg.tick_cycles
     is_stall = sw & args["stall"][t - args["t0"]]
 
-    # -- 1. token-bucket timers ------------------------------------
-    # host descheduled (software shaping): refills deferred, catch up on
-    # wakeup; hardware shaping and unshaped systems tick every cycle
-    pend = carry["sw_pend"] + cfg.tick_cycles
-    elapsed = jnp.where(sw, jnp.where(is_stall, 0, pend), cfg.tick_cycles)
-    carry["sw_pend"] = jnp.where(sw & is_stall, pend, 0)
-    carry["tb"] = tb.advance(carry["tb"], elapsed)
+    # Each stage below runs under a named scope (intake, grant, service,
+    # egress): op metadata only, so a device trace can attribute the
+    # tick's operations to its stages; the numerics are unchanged.
+    with jax.named_scope("intake"):
+        # -- 1. token-bucket timers ------------------------------------
+        # host descheduled (software shaping): refills deferred, catch up on
+        # wakeup; hardware shaping and unshaped systems tick every cycle
+        pend = carry["sw_pend"] + cfg.tick_cycles
+        elapsed = jnp.where(sw, jnp.where(is_stall, 0, pend), cfg.tick_cycles)
+        carry["sw_pend"] = jnp.where(sw & is_stall, pend, 0)
+        carry["tb"] = tb.advance(carry["tb"], elapsed)
 
-    # -- 2. arrivals -> per-flow queues (single gather) ----------------
-    # one [N, k_arr] gather of the next candidate arrivals per flow; the
-    # due set is a per-row prefix (traces are time-sorted, INF-padded), so
-    # counts replace the old k_arr-iteration drain loop exactly: the first
-    # `room` due messages are taken, the remaining due ones dropped.
-    M = arr_t.shape[1]
-    jj_a = jnp.arange(cfg.k_arr, dtype=jnp.int32)
-    pos = carry["arr_ptr"][:, None] + jj_a[None, :]
-    gidx = jnp.minimum(pos, M - 1)
-    nxt_t = arr_t[iota_n[:, None], gidx]
-    nxt_s = arr_sz[iota_n[:, None], gidx]
-    due = (nxt_t < now_end) & (pos < M)
-    n_due = due.astype(jnp.int32).sum(1)
-    n_take = jnp.minimum(n_due, jnp.maximum(cfg.qlen - carry["q_cnt"], 0))
-    take = due & (jj_a[None, :] < n_take[:, None])
-    slot = (carry["q_head"][:, None] + carry["q_cnt"][:, None]
-            + jj_a[None, :]) % cfg.qlen
-    row = jnp.where(take, iota_n[:, None], N)        # OOB rows are dropped
-    carry["q_sz"] = carry["q_sz"].at[row, slot].set(nxt_s, mode="drop")
-    carry["q_at"] = carry["q_at"].at[row, slot].set(nxt_t, mode="drop")
-    carry["q_cnt"] = carry["q_cnt"] + n_take
-    carry["arr_ptr"] = carry["arr_ptr"] + n_due
-    carry["c_drops"] = carry["c_drops"] + (n_due - n_take)
+        # -- 2. arrivals -> per-flow queues (single gather) ----------------
+        # one [N, k_arr] gather of the next candidate arrivals per flow; the
+        # due set is a per-row prefix (traces are time-sorted, INF-padded), so
+        # counts replace the old k_arr-iteration drain loop exactly: the first
+        # `room` due messages are taken, the remaining due ones dropped.
+        M = arr_t.shape[1]
+        jj_a = jnp.arange(cfg.k_arr, dtype=jnp.int32)
+        pos = carry["arr_ptr"][:, None] + jj_a[None, :]
+        gidx = jnp.minimum(pos, M - 1)
+        nxt_t = arr_t[iota_n[:, None], gidx]
+        nxt_s = arr_sz[iota_n[:, None], gidx]
+        due = (nxt_t < now_end) & (pos < M)
+        n_due = due.astype(jnp.int32).sum(1)
+        n_take = jnp.minimum(n_due, jnp.maximum(cfg.qlen - carry["q_cnt"], 0))
+        take = due & (jj_a[None, :] < n_take[:, None])
+        slot = (carry["q_head"][:, None] + carry["q_cnt"][:, None]
+                + jj_a[None, :]) % cfg.qlen
+        row = jnp.where(take, iota_n[:, None], N)        # OOB rows are dropped
+        carry["q_sz"] = carry["q_sz"].at[row, slot].set(nxt_s, mode="drop")
+        carry["q_at"] = carry["q_at"].at[row, slot].set(nxt_t, mode="drop")
+        carry["q_cnt"] = carry["q_cnt"] + n_take
+        carry["arr_ptr"] = carry["arr_ptr"] + n_due
+        carry["c_drops"] = carry["c_drops"] + (n_due - n_take)
 
-    # -- 3. per-tick link budgets ------------------------------------
-    budget = bpc * cfg.tick_cycles + carry["lres"]  # [2] bytes
-    # extra resource axes (R_res = R-1; 0 in the scalar default).  R_res is
-    # a *static* shape, so every resource op below sits behind a python
-    # `if R_res:` guard — the R=1 compiled graph is structurally identical
-    # to the pre-vector engine, which is what guarantees the bitwise
-    # degenerate contract.  The empty [0] arrays still thread through the
-    # cond/loop state tuples so branch signatures stay consistent.
-    R_res = args["res_cap"].shape[0]
-    res_bud = args["res_cap"] * cfg.tick_cycles + carry["res_res"]
-    res_w_in, res_w_eg = args["res_w_in"], args["res_w_eg"]
-    if R_res:
-        # axes a flow charges in EITHER direction: its grants stall while
-        # any of them is in debt.  Only the grant stage is gated — egress
-        # charges its bytes as additional debt when it pops (gating pops
-        # too would let the earlier grant stage starve egress forever at
-        # saturation); sustainable ingress goodput on a saturated axis is
-        # then cap / (w_in + w_eg * egress_ratio), which is exactly the
-        # demand-coefficient algebra CapacityEntry margins use.
-        res_w_any = (res_w_in > 0.0) | (res_w_eg > 0.0)
-
-    # -- 4. shaper + arbiter grants ----------------------------------
-    def grant_inputs(c, budget, res_bud):
-        """Head-of-line state + eligibility + arbiter key per flow."""
-        head_sz = c["q_sz"][iota_n, c["q_head"]]
-        head_at = c["q_at"][iota_n, c["q_head"]]
-        have = c["q_cnt"] > 0
-        cost = tb.cost_of(c["tb"], head_sz)
-        tok_ok = jnp.logical_or(~shaped, c["tb"].tokens >= cost)
-        a_of = fl_accel
-        aq_room = jnp.logical_and(
-            c["aq_cnt"][a_of] < cfg.aq_len,
-            c["aq_bytes"][a_of] + head_sz <= cfg.aq_byte_cap)
-        cred_ok = c["credits_used"] < credits
-        # A message may start whenever the link has *any* remaining
-        # budget; it then drives the budget negative, which models its
-        # serialization time (the link stays busy / in debt until the
-        # per-tick replenishment pays it off).
-        bud_f = jnp.where(fl_in_dir == 2, jnp.float32(3e38),
-                          budget[jnp.minimum(fl_in_dir, 1)])
-        bud_ok = bud_f > 0.0
-        elig = (have & tok_ok & aq_room & cred_ok & bud_ok & fl_mask
-                & jnp.logical_not(is_stall))
+        # -- 3. per-tick link budgets ------------------------------------
+        budget = bpc * cfg.tick_cycles + carry["lres"]  # [2] bytes
+        # extra resource axes (R_res = R-1; 0 in the scalar default).  R_res is
+        # a *static* shape, so every resource op below sits behind a python
+        # `if R_res:` guard — the R=1 compiled graph is structurally identical
+        # to the pre-vector engine, which is what guarantees the bitwise
+        # degenerate contract.  The empty [0] arrays still thread through the
+        # cond/loop state tuples so branch signatures stay consistent.
+        R_res = args["res_cap"].shape[0]
+        res_bud = args["res_cap"] * cfg.tick_cycles + carry["res_res"]
+        res_w_in, res_w_eg = args["res_w_in"], args["res_w_eg"]
         if R_res:
-            # a flow stalls while ANY axis it demands is in debt (same
-            # start-when-positive semantics as the link budget above)
-            res_ok = jnp.all((~res_w_any) | (res_bud[:, None] > 0.0),
-                             axis=0)
-            elig = elig & res_ok
+            # axes a flow charges in EITHER direction: its grants stall while
+            # any of them is in debt.  Only the grant stage is gated — egress
+            # charges its bytes as additional debt when it pops (gating pops
+            # too would let the earlier grant stage starve egress forever at
+            # saturation); sustainable ingress goodput on a saturated axis is
+            # then cap / (w_in + w_eg * egress_ratio), which is exactly the
+            # demand-coefficient algebra CapacityEntry margins use.
+            res_w_any = (res_w_in > 0.0) | (res_w_eg > 0.0)
 
-        # arbiter key (lower = served first), selected by the traced mode
-        # word.  Pure RR cycles by lane index modulo the *static* lane
-        # count N: for any active subset this induces exactly the cyclic
-        # lane order after rr_ptr, so it is grant-for-grant identical to
-        # the old modulo-n_act key when active lanes form a prefix AND
-        # stays correct when departures punch holes mid-table (mod n_act
-        # would alias two active lanes onto one key there).  The WRR/WFQ/
-        # priority tie-break term keeps the modulo-n_act *values* so those
-        # float keys stay bitwise-identical between padded and unpadded
-        # runs.
-        rr_cyc = ((iota_n - c["rr_ptr"] - 1) % N).astype(jnp.float32)
-        rr_key = ((iota_n - c["rr_ptr"] - 1) % n_act).astype(jnp.float32)
-        key = jnp.where(
-            arb_rr, rr_cyc,
-            jnp.where(arb == ARB_PRIORITY, -fl_prio * 1e6 + rr_key,
-                      c["vft"] + 1e-6 * rr_key))        # WRR / WFQ
-        key = jnp.where(elig, key, jnp.float32(3e38))
-        return head_sz, head_at, cost, elig, key
-
-    def grant_body(_, st):
-        c, budget, res_bud = st
-        head_sz, head_at, cost, elig, key = grant_inputs(c, budget, res_bud)
-        g = jnp.argmin(key).astype(jnp.int32)
-        ok = elig[g]
-
-        sz = head_sz[g]
-        at = head_at[g]
-        onehot = (iota_n == g) & ok
-        # consume tokens (transparent when unshaped)
-        c["tb"] = c["tb"]._replace(
-            tokens=c["tb"].tokens - jnp.where(onehot & shaped, cost, 0))
-        # pop flow queue
-        c["q_head"] = (c["q_head"] + onehot) % cfg.qlen
-        c["q_cnt"] = c["q_cnt"] - onehot
-        # link budget + credits (per-message fabric overhead included)
-        dir_idx = jnp.minimum(fl_in_dir[g], 1)
-        spend = jnp.where((fl_in_dir[g] != 2) & ok,
-                          sz.astype(jnp.float32) + ovh, 0.0)
-        budget = budget.at[dir_idx].add(-spend)
-        if R_res:
-            # charge the granted message's ingress demand on every axis
-            # (payload bytes only — the TLP overhead is a link artifact)
-            res_bud = res_bud - jnp.where(
-                ok, res_w_in[:, g] * sz.astype(jnp.float32), 0.0)
-        c["credits_used"] = c["credits_used"] + ok.astype(jnp.int32)
-        # accel queue push
-        a = fl_accel[g]
-        slot = (c["aq_head"][a] + c["aq_cnt"][a]) % cfg.aq_len
-        c["aq_sz"] = c["aq_sz"].at[a, slot].set(
-            jnp.where(ok, sz, c["aq_sz"][a, slot]))
-        c["aq_fl"] = c["aq_fl"].at[a, slot].set(
-            jnp.where(ok, g, c["aq_fl"][a, slot]))
-        c["aq_at"] = c["aq_at"].at[a, slot].set(
-            jnp.where(ok, at, c["aq_at"][a, slot]))
-        c["aq_cnt"] = c["aq_cnt"].at[a].add(ok.astype(jnp.int32))
-        c["aq_bytes"] = c["aq_bytes"].at[a].add(jnp.where(ok, sz, 0))
-        # arbiter state.  WRR is message-granular (one packet per flow
-        # per round — how the paper's Host_noTS FPGA arbiter behaves,
-        # letting large messages steal bytes); WFQ is byte-granular.
-        c["rr_ptr"] = jnp.where(ok, g, c["rr_ptr"])
-        vft_inc = jnp.where(arb == ARB_WRR, jnp.float32(1.0),
-                            sz.astype(jnp.float32)) / fl_w
-        c["vft"] = c["vft"] + jnp.where(onehot, vft_inc, 0.0)
-        # counters
-        c["c_adm_msgs"] = c["c_adm_msgs"] + onehot.astype(jnp.int32)
-        lo = c["c_adm_b_lo"] + jnp.where(onehot, sz, 0)
-        c["c_adm_b_hi"] = c["c_adm_b_hi"] + (lo >> 20)
-        c["c_adm_b_lo"] = lo & 0xFFFFF
-        return c, budget, res_bud
-
-    def seq_grants(c, budget, res_bud, *_aux):
-        c, budget, res_bud = _fori(cfg.k_grant, grant_body,
-                                   (c, budget, res_bud))
-        return c, budget, res_bud
-
-    use_fast = cfg.grant_fast and cfg.k_grant > 1 and N > 1
-    if use_fast:
-        # One-shot grant selection for the common uncontended RR tick.
-        # Sorting eligible flows by the RR key visits them in exactly the
-        # cyclic order the sequential argmin loop would (each grant moves
-        # rr_ptr to the granted flow, so the next argmin is the next
-        # eligible flow after it); eligibility is monotone within a tick
-        # (budgets/credits/queues only move toward ineligibility), so the
-        # first-K selection equals the sequential one whenever
-        #   (a) every candidate passes its *cumulative* budget / credit /
-        #       accel-queue check (prefix sums below), and
-        #   (b) no flow could be granted twice (either >= k_grant flows
-        #       are eligible, or every eligible flow has a single queued
-        #       message).
-        # Any contended (or non-RR) tick falls back to the sequential loop.
-        K = min(cfg.k_grant, N)
-        head_sz, head_at, cost, elig, key = grant_inputs(carry, budget,
-                                                         res_bud)
-        order = jnp.argsort(key)[:K]             # candidate flows, RR order
-        valid = elig[order]                       # eligible prefix
-        vi = valid.astype(jnp.int32)
-        csz = head_sz[order]
-        cat = head_at[order]
-        ccost = cost[order]
-        cdir = fl_in_dir[order]
-        d01 = jnp.minimum(cdir, 1)
-        cacc = fl_accel[order]
-        spend = jnp.where((cdir != 2) & valid,
-                          csz.astype(jnp.float32) + ovh, 0.0)
-        # Prefix sums over the candidates.  The f32 ones are matmuls at
-        # HIGHEST precision: at DEFAULT, XLA:TPU runs an f32 dot as one
-        # bf16 pass, which rounds byte sums to 8 mantissa bits (XLA:CPU
-        # ignores the precision field, so CPU results are unchanged).  The
-        # int32 ones are masked sums — exact on every backend.
-        hi = jax.lax.Precision.HIGHEST
-        lt_i = jnp.tril(jnp.ones((K, K), jnp.int32), -1)   # [j, i]: i < j
-        lt_f = lt_i.astype(jnp.float32)
-        same_dir = (d01[None, :] == d01[:, None])
-        cum_spend = jnp.dot(lt_f * same_dir.astype(jnp.float32), spend,
-                            precision=hi)
-        bud_ok = (cdir == 2) | (budget[d01] - cum_spend > 0.0)
-        same_acc = (cacc[None, :] == cacc[:, None]).astype(jnp.int32)
-        cnt_before = jnp.sum(lt_i * same_acc * vi[None, :], axis=1)
-        byt_before = jnp.sum(lt_i * same_acc
-                             * jnp.where(valid, csz, 0)[None, :], axis=1)
-        aq_ok = ((carry["aq_cnt"][cacc] + cnt_before < cfg.aq_len)
-                 & (carry["aq_bytes"][cacc] + byt_before + csz
-                    <= cfg.aq_byte_cap))
-        idx_before = jnp.sum(lt_i * vi[None, :], axis=1)
-        cred_ok = carry["credits_used"] + idx_before < credits
-        ok_all = jnp.all(~valid | (bud_ok & aq_ok & cred_ok))
-        if R_res:
-            # cumulative per-axis check: candidate j must see a positive
-            # bucket after the spends of every valid candidate before it
-            # (the sequential loop's mid-tick eligibility re-check)
-            c_any = res_w_any[:, order]                         # [R, K]
-            c_rspend = (res_w_in[:, order]
-                        * jnp.where(valid, csz, 0).astype(jnp.float32))
-            cum_res = jnp.dot(c_rspend, lt_f.T, precision=hi)   # [R, K]
-            res_ok_c = jnp.all(
-                (~c_any) | (res_bud[:, None] - cum_res > 0.0), axis=0)
-            ok_all = ok_all & jnp.all(~valid | res_ok_c)
-        n_elig = jnp.sum(elig.astype(jnp.int32))
-        regrant_safe = ((n_elig >= cfg.k_grant)
-                        | jnp.all(~elig | (carry["q_cnt"] <= 1)))
-        fast_pred = ok_all & regrant_safe & arb_rr
-
-        # Under vmap (run_window_batch) this cond lowers to a select that
-        # evaluates BOTH branches per lane.  That waste is accepted on
-        # purpose: batched and serial runs then share the exact per-lane
-        # computation, which is what guarantees simulate_batch() counters
-        # bitwise-match serial simulate() — stripping the fast path from
-        # batch engines would instead rely on fast==sequential holding to
-        # the last float ulp.  Callers who want a leaner batch engine can
-        # set SimConfig.grant_fast=False on both sides.
-        def vec_grants(c, budget, res_bud, order, valid, vi, csz, cat,
-                       ccost, cdir, d01, cacc, spend, cnt_before):
-            c["tb"] = c["tb"]._replace(
-                tokens=c["tb"].tokens.at[order].add(
-                    -jnp.where(valid & shaped, ccost, 0)))
+    with jax.named_scope("grant"):
+        # -- 4. shaper + arbiter grants ----------------------------------
+        def grant_inputs(c, budget, res_bud):
+            """Head-of-line state + eligibility + arbiter key per flow."""
+            head_sz = c["q_sz"][iota_n, c["q_head"]]
+            head_at = c["q_at"][iota_n, c["q_head"]]
+            have = c["q_cnt"] > 0
+            cost = tb.cost_of(c["tb"], head_sz)
+            tok_ok = jnp.logical_or(~shaped, c["tb"].tokens >= cost)
+            a_of = fl_accel
+            aq_room = jnp.logical_and(
+                c["aq_cnt"][a_of] < cfg.aq_len,
+                c["aq_bytes"][a_of] + head_sz <= cfg.aq_byte_cap)
+            cred_ok = c["credits_used"] < credits
+            # A message may start whenever the link has *any* remaining
+            # budget; it then drives the budget negative, which models its
+            # serialization time (the link stays busy / in debt until the
+            # per-tick replenishment pays it off).
+            bud_f = jnp.where(fl_in_dir == 2, jnp.float32(3e38),
+                              budget[jnp.minimum(fl_in_dir, 1)])
+            bud_ok = bud_f > 0.0
+            elig = (have & tok_ok & aq_room & cred_ok & bud_ok & fl_mask
+                    & jnp.logical_not(is_stall))
             if R_res:
-                # subtract in the exact sequential chain order: non-dyadic
-                # demand coefficients make float sums order-sensitive, and
-                # the carried residue must match the sequential loop's
-                r_spend = (res_w_in[:, order]
-                           * jnp.where(valid, csz, 0).astype(jnp.float32))
-                for j in range(K):
-                    res_bud = res_bud - r_spend[:, j]
-            c["q_head"] = (c["q_head"]
-                           + jnp.zeros((N,), jnp.int32).at[order].add(vi)) \
-                % cfg.qlen
-            c["q_cnt"] = c["q_cnt"] - jnp.zeros((N,), jnp.int32) \
-                .at[order].add(vi)
-            budget = budget - jnp.zeros((2,), jnp.float32).at[d01].add(spend)
-            n_g = jnp.sum(vi)
-            c["credits_used"] = c["credits_used"] + n_g
-            slot = (c["aq_head"][cacc] + c["aq_cnt"][cacc] + cnt_before) \
-                % cfg.aq_len
-            row = jnp.where(valid, cacc, A)       # OOB rows are dropped
-            c["aq_sz"] = c["aq_sz"].at[row, slot].set(csz, mode="drop")
-            c["aq_fl"] = c["aq_fl"].at[row, slot].set(order, mode="drop")
-            c["aq_at"] = c["aq_at"].at[row, slot].set(cat, mode="drop")
-            c["aq_cnt"] = c["aq_cnt"].at[cacc].add(vi)
-            c["aq_bytes"] = c["aq_bytes"].at[cacc].add(
-                jnp.where(valid, csz, 0))
-            c["rr_ptr"] = jnp.where(
-                n_g > 0, order[jnp.maximum(n_g - 1, 0)], c["rr_ptr"])
+                # a flow stalls while ANY axis it demands is in debt (same
+                # start-when-positive semantics as the link budget above)
+                res_ok = jnp.all((~res_w_any) | (res_bud[:, None] > 0.0),
+                                 axis=0)
+                elig = elig & res_ok
+
+            # arbiter key (lower = served first), selected by the traced mode
+            # word.  Pure RR cycles by lane index modulo the *static* lane
+            # count N: for any active subset this induces exactly the cyclic
+            # lane order after rr_ptr, so it is grant-for-grant identical to
+            # the old modulo-n_act key when active lanes form a prefix AND
+            # stays correct when departures punch holes mid-table (mod n_act
+            # would alias two active lanes onto one key there).  The WRR/WFQ/
+            # priority tie-break term keeps the modulo-n_act *values* so those
+            # float keys stay bitwise-identical between padded and unpadded
+            # runs.
+            rr_cyc = ((iota_n - c["rr_ptr"] - 1) % N).astype(jnp.float32)
+            rr_key = ((iota_n - c["rr_ptr"] - 1) % n_act).astype(jnp.float32)
+            key = jnp.where(
+                arb_rr, rr_cyc,
+                jnp.where(arb == ARB_PRIORITY, -fl_prio * 1e6 + rr_key,
+                          c["vft"] + 1e-6 * rr_key))        # WRR / WFQ
+            key = jnp.where(elig, key, jnp.float32(3e38))
+            return head_sz, head_at, cost, elig, key
+
+        def grant_body(_, st):
+            c, budget, res_bud = st
+            head_sz, head_at, cost, elig, key = grant_inputs(c, budget, res_bud)
+            g = jnp.argmin(key).astype(jnp.int32)
+            ok = elig[g]
+
+            sz = head_sz[g]
+            at = head_at[g]
+            onehot = (iota_n == g) & ok
+            # consume tokens (transparent when unshaped)
+            c["tb"] = c["tb"]._replace(
+                tokens=c["tb"].tokens - jnp.where(onehot & shaped, cost, 0))
+            # pop flow queue
+            c["q_head"] = (c["q_head"] + onehot) % cfg.qlen
+            c["q_cnt"] = c["q_cnt"] - onehot
+            # link budget + credits (per-message fabric overhead included)
+            dir_idx = jnp.minimum(fl_in_dir[g], 1)
+            spend = jnp.where((fl_in_dir[g] != 2) & ok,
+                              sz.astype(jnp.float32) + ovh, 0.0)
+            budget = budget.at[dir_idx].add(-spend)
+            if R_res:
+                # charge the granted message's ingress demand on every axis
+                # (payload bytes only — the TLP overhead is a link artifact)
+                res_bud = res_bud - jnp.where(
+                    ok, res_w_in[:, g] * sz.astype(jnp.float32), 0.0)
+            c["credits_used"] = c["credits_used"] + ok.astype(jnp.int32)
+            # accel queue push
+            a = fl_accel[g]
+            slot = (c["aq_head"][a] + c["aq_cnt"][a]) % cfg.aq_len
+            c["aq_sz"] = c["aq_sz"].at[a, slot].set(
+                jnp.where(ok, sz, c["aq_sz"][a, slot]))
+            c["aq_fl"] = c["aq_fl"].at[a, slot].set(
+                jnp.where(ok, g, c["aq_fl"][a, slot]))
+            c["aq_at"] = c["aq_at"].at[a, slot].set(
+                jnp.where(ok, at, c["aq_at"][a, slot]))
+            c["aq_cnt"] = c["aq_cnt"].at[a].add(ok.astype(jnp.int32))
+            c["aq_bytes"] = c["aq_bytes"].at[a].add(jnp.where(ok, sz, 0))
+            # arbiter state.  WRR is message-granular (one packet per flow
+            # per round — how the paper's Host_noTS FPGA arbiter behaves,
+            # letting large messages steal bytes); WFQ is byte-granular.
+            c["rr_ptr"] = jnp.where(ok, g, c["rr_ptr"])
             vft_inc = jnp.where(arb == ARB_WRR, jnp.float32(1.0),
-                                csz.astype(jnp.float32)) / fl_w[order]
-            c["vft"] = c["vft"].at[order].add(jnp.where(valid, vft_inc, 0.0))
-            c["c_adm_msgs"] = c["c_adm_msgs"].at[order].add(vi)
-            lo = c["c_adm_b_lo"].at[order].add(jnp.where(valid, csz, 0))
+                                sz.astype(jnp.float32)) / fl_w
+            c["vft"] = c["vft"] + jnp.where(onehot, vft_inc, 0.0)
+            # counters
+            c["c_adm_msgs"] = c["c_adm_msgs"] + onehot.astype(jnp.int32)
+            lo = c["c_adm_b_lo"] + jnp.where(onehot, sz, 0)
             c["c_adm_b_hi"] = c["c_adm_b_hi"] + (lo >> 20)
             c["c_adm_b_lo"] = lo & 0xFFFFF
             return c, budget, res_bud
 
-        carry, budget, res_bud = jax.lax.cond(
-            fast_pred, vec_grants, seq_grants,
-            carry, budget, res_bud, order, valid, vi, csz, cat, ccost,
-            cdir, d01, cacc, spend, cnt_before)
-    else:
-        carry, budget, res_bud = seq_grants(carry, budget, res_bud)
+        def seq_grants(c, budget, res_bud, *_aux):
+            c, budget, res_bud = _fori(cfg.k_grant, grant_body,
+                                       (c, budget, res_bud))
+            return c, budget, res_bud
 
-    # -- 5. accelerator service --------------------------------------
-    # sequential reference: one accel per iteration, pass-major order
-    # (iteration i serves accel i % A on pass i // A)
-    def srv_body(i, c):
-        a = i % A
-        act = ac_mask[a]      # padded accel rows (ragged batching) are inert
-        lanes_a = c["lanes"][a]
-        lane = jnp.argmin(lanes_a).astype(jnp.int32)
-        # a lane that frees during this tick may chain back-to-back
-        # (no tick-quantization idle gap between messages)
-        free = lanes_a[lane] < jnp.float32(now_end)
-        ok = free & (c["aq_cnt"][a] > 0) & act
-        h = c["aq_head"][a]
-        sz = c["aq_sz"][a, h]
-        fl = c["aq_fl"][a, h]
-        at = c["aq_at"][a, h]
-        svc = interp_grid(svc_tab, a, sz.astype(jnp.float32))
-        esz = interp_grid(eg_tab, a, sz.astype(jnp.float32))
-        esz = jnp.where(fl_eg_full[fl], sz.astype(jnp.float32), esz)
-        end = jnp.maximum(lanes_a[lane], jnp.float32(now)) + svc
-        c["lanes"] = c["lanes"].at[a, lane].set(
-            jnp.where(ok, end, lanes_a[lane]))
-        # the pop is a masked select over the accel axis, not a scatter-add
-        # at [a]: unrolled, `a` is a constant, and XLA:TPU miscompiled those
-        # constant-index scatter-adds in the unbatched engine (idle accels'
-        # queues were popped too).  Integer selects: the same bits anywhere.
-        pop = (jnp.arange(A, dtype=jnp.int32) == a) & ok
-        c["aq_head"] = (c["aq_head"] + jnp.where(pop, 1, 0)) % cfg.aq_len
-        c["aq_cnt"] = c["aq_cnt"] - jnp.where(pop, 1, 0)
-        c["aq_bytes"] = c["aq_bytes"] - jnp.where(pop, sz, 0)
-        # host-processing delay (software-mediated shaping only; the LCG
-        # advances once per *active-accelerator* iteration whenever shaping
-        # is software, busy or idle, exactly like the closed-form batch
-        # draw below — padded rows draw nothing, so a ragged element's
-        # jitter stream matches its unpadded serial run)
-        r = c["rng"] * _LCG_A + _LCG_C
-        c["rng"] = jnp.where(sw & act, r, c["rng"])
-        u = (jnp.abs(r) % 65536).astype(jnp.float32) / 65536.0
-        hostd = jnp.where(sw, args["sw_delay"] + (u ** 4) * args["sw_jit"],
-                          jnp.float32(0.0))
-        ready = (end + hostd).astype(jnp.int32)
-        # egress queue push
-        d = fl_eg_dir[fl]
-        slot = (c["eq_head"][d] + c["eq_cnt"][d]) % cfg.eq_len
-        full = c["eq_cnt"][d] >= cfg.eq_len
-        okq = ok & jnp.logical_not(full)
-        c["eq_sz"] = c["eq_sz"].at[d, slot].set(
-            jnp.where(okq, jnp.maximum(esz.astype(jnp.int32), 1),
-                      c["eq_sz"][d, slot]))
-        c["eq_isz"] = c["eq_isz"].at[d, slot].set(
-            jnp.where(okq, sz, c["eq_isz"][d, slot]))
-        c["eq_fl"] = c["eq_fl"].at[d, slot].set(
-            jnp.where(okq, fl, c["eq_fl"][d, slot]))
-        c["eq_at"] = c["eq_at"].at[d, slot].set(
-            jnp.where(okq, at, c["eq_at"][d, slot]))
-        c["eq_rd"] = c["eq_rd"].at[d, slot].set(
-            jnp.where(okq, ready, c["eq_rd"][d, slot]))
-        c["eq_cnt"] = c["eq_cnt"].at[d].add(okq.astype(jnp.int32))
-        return c
+        use_fast = cfg.grant_fast and cfg.k_grant > 1 and N > 1
+        if use_fast:
+            # One-shot grant selection for the common uncontended RR tick.
+            # Sorting eligible flows by the RR key visits them in exactly the
+            # cyclic order the sequential argmin loop would (each grant moves
+            # rr_ptr to the granted flow, so the next argmin is the next
+            # eligible flow after it); eligibility is monotone within a tick
+            # (budgets/credits/queues only move toward ineligibility), so the
+            # first-K selection equals the sequential one whenever
+            #   (a) every candidate passes its *cumulative* budget / credit /
+            #       accel-queue check (prefix sums below), and
+            #   (b) no flow could be granted twice (either >= k_grant flows
+            #       are eligible, or every eligible flow has a single queued
+            #       message).
+            # Any contended (or non-RR) tick falls back to the sequential loop.
+            K = min(cfg.k_grant, N)
+            head_sz, head_at, cost, elig, key = grant_inputs(carry, budget,
+                                                             res_bud)
+            order = jnp.argsort(key)[:K]             # candidate flows, RR order
+            valid = elig[order]                       # eligible prefix
+            vi = valid.astype(jnp.int32)
+            csz = head_sz[order]
+            cat = head_at[order]
+            ccost = cost[order]
+            cdir = fl_in_dir[order]
+            d01 = jnp.minimum(cdir, 1)
+            cacc = fl_accel[order]
+            spend = jnp.where((cdir != 2) & valid,
+                              csz.astype(jnp.float32) + ovh, 0.0)
+            # Prefix sums over the candidates.  The f32 ones are matmuls at
+            # HIGHEST precision: at DEFAULT, XLA:TPU runs an f32 dot as one
+            # bf16 pass, which rounds byte sums to 8 mantissa bits (XLA:CPU
+            # ignores the precision field, so CPU results are unchanged).  The
+            # int32 ones are masked sums — exact on every backend.
+            hi = jax.lax.Precision.HIGHEST
+            lt_i = jnp.tril(jnp.ones((K, K), jnp.int32), -1)   # [j, i]: i < j
+            lt_f = lt_i.astype(jnp.float32)
+            same_dir = (d01[None, :] == d01[:, None])
+            cum_spend = jnp.dot(lt_f * same_dir.astype(jnp.float32), spend,
+                                precision=hi)
+            bud_ok = (cdir == 2) | (budget[d01] - cum_spend > 0.0)
+            same_acc = (cacc[None, :] == cacc[:, None]).astype(jnp.int32)
+            cnt_before = jnp.sum(lt_i * same_acc * vi[None, :], axis=1)
+            byt_before = jnp.sum(lt_i * same_acc
+                                 * jnp.where(valid, csz, 0)[None, :], axis=1)
+            aq_ok = ((carry["aq_cnt"][cacc] + cnt_before < cfg.aq_len)
+                     & (carry["aq_bytes"][cacc] + byt_before + csz
+                        <= cfg.aq_byte_cap))
+            idx_before = jnp.sum(lt_i * vi[None, :], axis=1)
+            cred_ok = carry["credits_used"] + idx_before < credits
+            ok_all = jnp.all(~valid | (bud_ok & aq_ok & cred_ok))
+            if R_res:
+                # cumulative per-axis check: candidate j must see a positive
+                # bucket after the spends of every valid candidate before it
+                # (the sequential loop's mid-tick eligibility re-check)
+                c_any = res_w_any[:, order]                         # [R, K]
+                c_rspend = (res_w_in[:, order]
+                            * jnp.where(valid, csz, 0).astype(jnp.float32))
+                cum_res = jnp.dot(c_rspend, lt_f.T, precision=hi)   # [R, K]
+                res_ok_c = jnp.all(
+                    (~c_any) | (res_bud[:, None] - cum_res > 0.0), axis=0)
+                ok_all = ok_all & jnp.all(~valid | res_ok_c)
+            n_elig = jnp.sum(elig.astype(jnp.int32))
+            regrant_safe = ((n_elig >= cfg.k_grant)
+                            | jnp.all(~elig | (carry["q_cnt"] <= 1)))
+            fast_pred = ok_all & regrant_safe & arb_rr
 
-    def seq_srv(c):
-        return _fori(A * cfg.k_srv, srv_body, c)
+            # Under vmap (run_window_batch) this cond lowers to a select that
+            # evaluates BOTH branches per lane.  That waste is accepted on
+            # purpose: batched and serial runs then share the exact per-lane
+            # computation, which is what guarantees simulate_batch() counters
+            # bitwise-match serial simulate() — stripping the fast path from
+            # batch engines would instead rely on fast==sequential holding to
+            # the last float ulp.  Callers who want a leaner batch engine can
+            # set SimConfig.grant_fast=False on both sides.
+            def vec_grants(c, budget, res_bud, order, valid, vi, csz, cat,
+                           ccost, cdir, d01, cacc, spend, cnt_before):
+                c["tb"] = c["tb"]._replace(
+                    tokens=c["tb"].tokens.at[order].add(
+                        -jnp.where(valid & shaped, ccost, 0)))
+                if R_res:
+                    # subtract in the exact sequential chain order: non-dyadic
+                    # demand coefficients make float sums order-sensitive, and
+                    # the carried residue must match the sequential loop's
+                    r_spend = (res_w_in[:, order]
+                               * jnp.where(valid, csz, 0).astype(jnp.float32))
+                    for j in range(K):
+                        res_bud = res_bud - r_spend[:, j]
+                c["q_head"] = (c["q_head"]
+                               + jnp.zeros((N,), jnp.int32).at[order].add(vi)) \
+                    % cfg.qlen
+                c["q_cnt"] = c["q_cnt"] - jnp.zeros((N,), jnp.int32) \
+                    .at[order].add(vi)
+                budget = budget - jnp.zeros((2,), jnp.float32).at[d01].add(spend)
+                n_g = jnp.sum(vi)
+                c["credits_used"] = c["credits_used"] + n_g
+                slot = (c["aq_head"][cacc] + c["aq_cnt"][cacc] + cnt_before) \
+                    % cfg.aq_len
+                row = jnp.where(valid, cacc, A)       # OOB rows are dropped
+                c["aq_sz"] = c["aq_sz"].at[row, slot].set(csz, mode="drop")
+                c["aq_fl"] = c["aq_fl"].at[row, slot].set(order, mode="drop")
+                c["aq_at"] = c["aq_at"].at[row, slot].set(cat, mode="drop")
+                c["aq_cnt"] = c["aq_cnt"].at[cacc].add(vi)
+                c["aq_bytes"] = c["aq_bytes"].at[cacc].add(
+                    jnp.where(valid, csz, 0))
+                c["rr_ptr"] = jnp.where(
+                    n_g > 0, order[jnp.maximum(n_g - 1, 0)], c["rr_ptr"])
+                vft_inc = jnp.where(arb == ARB_WRR, jnp.float32(1.0),
+                                    csz.astype(jnp.float32)) / fl_w[order]
+                c["vft"] = c["vft"].at[order].add(jnp.where(valid, vft_inc, 0.0))
+                c["c_adm_msgs"] = c["c_adm_msgs"].at[order].add(vi)
+                lo = c["c_adm_b_lo"].at[order].add(jnp.where(valid, csz, 0))
+                c["c_adm_b_hi"] = c["c_adm_b_hi"] + (lo >> 20)
+                c["c_adm_b_lo"] = lo & 0xFFFFF
+                return c, budget, res_bud
 
-    # Vectorized service pays off only once the stage is wide enough:
-    # measured on XLA-CPU, narrow service next to the vectorized egress
-    # stage fuses pathologically (3x slower than the unrolled loop), while
-    # wide stages gain 2-4x.  The knee (8 on XLA-CPU) is backend-dependent:
-    # SimConfig.service_vec_min / $REPRO_SERVICE_VEC_MIN override it.  The
-    # threshold is static, so serial and batched runs share the path.
-    if cfg.stage_fast and A * cfg.k_srv >= cfg.service_vec_min:
-        # Prefix-sum slot assignment (the treatment PR 1 gave RR grants):
-        # sort each accelerator's lanes by busy-time; the k-th queued
-        # message starts on the k-th least-busy lane.  This equals the
-        # sequential argmin walk whenever no assigned lane frees again
-        # within this tick (its end >= now_end): assigned lanes then sort
-        # strictly after every still-free lane, so the sequential argmin
-        # sequence is exactly the sorted order.  A chaining tick (tiny
-        # service times) falls back to the sequential loop.
-        Ks = cfg.k_srv
-        ia = jnp.arange(A, dtype=jnp.int32)
-        kk = jnp.arange(Ks, dtype=jnp.int32)
-        kl = jnp.minimum(kk, cfg.lmax - 1)
-        sl = jnp.sort(carry["lanes"], axis=1)[:, kl]       # [A, Ks]
-        si = jnp.argsort(carry["lanes"], axis=1)[:, kl].astype(jnp.int32)
-        free = (sl < jnp.float32(now_end)) & (kk < cfg.lmax)[None, :]
-        have = kk[None, :] < carry["aq_cnt"][:, None]
-        s_ok = free & have & ac_mask[:, None]               # prefix rows
-        aslot = (carry["aq_head"][:, None] + kk[None, :]) % cfg.aq_len
-        s_sz = carry["aq_sz"][ia[:, None], aslot]
-        s_fl = carry["aq_fl"][ia[:, None], aslot]
-        s_at = carry["aq_at"][ia[:, None], aslot]
-        s_svc = _interp_mat(svc_tab, s_sz.astype(jnp.float32))
-        s_esz = _interp_mat(eg_tab, s_sz.astype(jnp.float32))
-        s_esz = jnp.where(fl_eg_full[s_fl], s_sz.astype(jnp.float32), s_esz)
-        s_end = jnp.maximum(sl, jnp.float32(now)) + s_svc
-        srv_fast = jnp.all(~s_ok | (s_end >= jnp.float32(now_end)))
+            carry, budget, res_bud = jax.lax.cond(
+                fast_pred, vec_grants, seq_grants,
+                carry, budget, res_bud, order, valid, vi, csz, cat, ccost,
+                cdir, d01, cacc, spend, cnt_before)
+        else:
+            carry, budget, res_bud = seq_grants(carry, budget, res_bud)
 
-        def vec_srv(c, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end):
-            n_start = s_ok.astype(jnp.int32).sum(1)
-            lrow = jnp.where(s_ok, ia[:, None], A)   # OOB rows are dropped
-            c["lanes"] = c["lanes"].at[lrow, si].set(s_end, mode="drop")
-            c["aq_head"] = (c["aq_head"] + n_start) % cfg.aq_len
-            c["aq_cnt"] = c["aq_cnt"] - n_start
-            c["aq_bytes"] = c["aq_bytes"] - jnp.where(s_ok, s_sz, 0).sum(1)
-            # host-processing delay: closed-form LCG draw for *active*
-            # iteration i = k*ac_n + a (padded accel rows draw nothing),
-            # bitwise-equal to the sequential per-step update of a run
-            # with only the active accelerators
-            powv, sumv = _lcg_tables(A * Ks)
-            it = jnp.minimum(kk[None, :] * ac_n + ia[:, None],
-                             A * Ks - 1)                     # [A, Ks]
-            r = c["rng"] * jnp.asarray(powv)[it] + jnp.asarray(sumv)[it]
-            adv = jnp.maximum(ac_n * Ks - 1, 0)
-            c["rng"] = jnp.where(sw, c["rng"] * jnp.asarray(powv)[adv]
-                                 + jnp.asarray(sumv)[adv], c["rng"])
+    with jax.named_scope("service"):
+        # -- 5. accelerator service --------------------------------------
+        # sequential reference: one accel per iteration, pass-major order
+        # (iteration i serves accel i % A on pass i // A)
+        def srv_body(i, c):
+            a = i % A
+            act = ac_mask[a]      # padded accel rows (ragged batching) are inert
+            lanes_a = c["lanes"][a]
+            lane = jnp.argmin(lanes_a).astype(jnp.int32)
+            # a lane that frees during this tick may chain back-to-back
+            # (no tick-quantization idle gap between messages)
+            free = lanes_a[lane] < jnp.float32(now_end)
+            ok = free & (c["aq_cnt"][a] > 0) & act
+            h = c["aq_head"][a]
+            sz = c["aq_sz"][a, h]
+            fl = c["aq_fl"][a, h]
+            at = c["aq_at"][a, h]
+            svc = interp_grid(svc_tab, a, sz.astype(jnp.float32))
+            esz = interp_grid(eg_tab, a, sz.astype(jnp.float32))
+            esz = jnp.where(fl_eg_full[fl], sz.astype(jnp.float32), esz)
+            end = jnp.maximum(lanes_a[lane], jnp.float32(now)) + svc
+            c["lanes"] = c["lanes"].at[a, lane].set(
+                jnp.where(ok, end, lanes_a[lane]))
+            # the pop is a masked select over the accel axis, not a scatter-add
+            # at [a]: unrolled, `a` is a constant, and XLA:TPU miscompiled those
+            # constant-index scatter-adds in the unbatched engine (idle accels'
+            # queues were popped too).  Integer selects: the same bits anywhere.
+            pop = (jnp.arange(A, dtype=jnp.int32) == a) & ok
+            c["aq_head"] = (c["aq_head"] + jnp.where(pop, 1, 0)) % cfg.aq_len
+            c["aq_cnt"] = c["aq_cnt"] - jnp.where(pop, 1, 0)
+            c["aq_bytes"] = c["aq_bytes"] - jnp.where(pop, sz, 0)
+            # host-processing delay (software-mediated shaping only; the LCG
+            # advances once per *active-accelerator* iteration whenever shaping
+            # is software, busy or idle, exactly like the closed-form batch
+            # draw below — padded rows draw nothing, so a ragged element's
+            # jitter stream matches its unpadded serial run)
+            r = c["rng"] * _LCG_A + _LCG_C
+            c["rng"] = jnp.where(sw & act, r, c["rng"])
             u = (jnp.abs(r) % 65536).astype(jnp.float32) / 65536.0
-            hostd = jnp.where(sw, args["sw_delay"]
-                              + (u ** 4) * args["sw_jit"], jnp.float32(0.0))
-            ready = (s_end + hostd).astype(jnp.int32)
-            # egress pushes in sequential iteration order (k-major flatten)
-            flat = lambda x: x.T.reshape(-1)                 # noqa: E731
-            okf = flat(s_ok)
-            d = fl_eg_dir[flat(s_fl)]
-            Mt = A * Ks
-            lt = jnp.tril(jnp.ones((Mt, Mt), jnp.int32), -1)
-            same_d = (d[None, :] == d[:, None]).astype(jnp.int32)
-            rank = jnp.sum(lt * same_d * okf.astype(jnp.int32)[None, :],
-                           axis=1)
-            okq = okf & (c["eq_cnt"][d] + rank < cfg.eq_len)
-            eslot = (c["eq_head"][d] + c["eq_cnt"][d] + rank) % cfg.eq_len
-            drow = jnp.where(okq, d, 3)           # OOB rows are dropped
-            c["eq_sz"] = c["eq_sz"].at[drow, eslot].set(
-                jnp.maximum(flat(s_esz).astype(jnp.int32), 1), mode="drop")
-            c["eq_isz"] = c["eq_isz"].at[drow, eslot].set(
-                flat(s_sz), mode="drop")
-            c["eq_fl"] = c["eq_fl"].at[drow, eslot].set(
-                flat(s_fl), mode="drop")
-            c["eq_at"] = c["eq_at"].at[drow, eslot].set(
-                flat(s_at), mode="drop")
-            c["eq_rd"] = c["eq_rd"].at[drow, eslot].set(
-                flat(ready), mode="drop")
-            c["eq_cnt"] = c["eq_cnt"] + jnp.zeros((3,), jnp.int32) \
-                .at[d].add(okq.astype(jnp.int32))
+            hostd = jnp.where(sw, args["sw_delay"] + (u ** 4) * args["sw_jit"],
+                              jnp.float32(0.0))
+            ready = (end + hostd).astype(jnp.int32)
+            # egress queue push
+            d = fl_eg_dir[fl]
+            slot = (c["eq_head"][d] + c["eq_cnt"][d]) % cfg.eq_len
+            full = c["eq_cnt"][d] >= cfg.eq_len
+            okq = ok & jnp.logical_not(full)
+            c["eq_sz"] = c["eq_sz"].at[d, slot].set(
+                jnp.where(okq, jnp.maximum(esz.astype(jnp.int32), 1),
+                          c["eq_sz"][d, slot]))
+            c["eq_isz"] = c["eq_isz"].at[d, slot].set(
+                jnp.where(okq, sz, c["eq_isz"][d, slot]))
+            c["eq_fl"] = c["eq_fl"].at[d, slot].set(
+                jnp.where(okq, fl, c["eq_fl"][d, slot]))
+            c["eq_at"] = c["eq_at"].at[d, slot].set(
+                jnp.where(okq, at, c["eq_at"][d, slot]))
+            c["eq_rd"] = c["eq_rd"].at[d, slot].set(
+                jnp.where(okq, ready, c["eq_rd"][d, slot]))
+            c["eq_cnt"] = c["eq_cnt"].at[d].add(okq.astype(jnp.int32))
             return c
 
-        carry = jax.lax.cond(srv_fast, vec_srv, lambda c, *_a: seq_srv(c),
-                             carry, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end)
-    else:
-        carry = seq_srv(carry)
+        def seq_srv(c):
+            return _fori(A * cfg.k_srv, srv_body, c)
 
-    # -- 6. egress link + completions ----------------------------------
-    dirs = jnp.arange(3, dtype=jnp.int32)
+        # Vectorized service pays off only once the stage is wide enough:
+        # measured on XLA-CPU, narrow service next to the vectorized egress
+        # stage fuses pathologically (3x slower than the unrolled loop), while
+        # wide stages gain 2-4x.  The knee (8 on XLA-CPU) is backend-dependent:
+        # SimConfig.service_vec_min / $REPRO_SERVICE_VEC_MIN override it.  The
+        # threshold is static, so serial and batched runs share the path.
+        if cfg.stage_fast and A * cfg.k_srv >= cfg.service_vec_min:
+            # Prefix-sum slot assignment (the treatment PR 1 gave RR grants):
+            # sort each accelerator's lanes by busy-time; the k-th queued
+            # message starts on the k-th least-busy lane.  This equals the
+            # sequential argmin walk whenever no assigned lane frees again
+            # within this tick (its end >= now_end): assigned lanes then sort
+            # strictly after every still-free lane, so the sequential argmin
+            # sequence is exactly the sorted order.  A chaining tick (tiny
+            # service times) falls back to the sequential loop.
+            Ks = cfg.k_srv
+            ia = jnp.arange(A, dtype=jnp.int32)
+            kk = jnp.arange(Ks, dtype=jnp.int32)
+            kl = jnp.minimum(kk, cfg.lmax - 1)
+            sl = jnp.sort(carry["lanes"], axis=1)[:, kl]       # [A, Ks]
+            si = jnp.argsort(carry["lanes"], axis=1)[:, kl].astype(jnp.int32)
+            free = (sl < jnp.float32(now_end)) & (kk < cfg.lmax)[None, :]
+            have = kk[None, :] < carry["aq_cnt"][:, None]
+            s_ok = free & have & ac_mask[:, None]               # prefix rows
+            aslot = (carry["aq_head"][:, None] + kk[None, :]) % cfg.aq_len
+            s_sz = carry["aq_sz"][ia[:, None], aslot]
+            s_fl = carry["aq_fl"][ia[:, None], aslot]
+            s_at = carry["aq_at"][ia[:, None], aslot]
+            s_svc = _interp_mat(svc_tab, s_sz.astype(jnp.float32))
+            s_esz = _interp_mat(eg_tab, s_sz.astype(jnp.float32))
+            s_esz = jnp.where(fl_eg_full[s_fl], s_sz.astype(jnp.float32), s_esz)
+            s_end = jnp.maximum(sl, jnp.float32(now)) + s_svc
+            srv_fast = jnp.all(~s_ok | (s_end >= jnp.float32(now_end)))
 
-    def eg_body(_, st):
-        c, budget, res_bud = st
-        h = c["eq_head"]                       # [3]
-        sz = c["eq_sz"][dirs, h]
-        isz = c["eq_isz"][dirs, h]
-        fl = c["eq_fl"][dirs, h]
-        at = c["eq_at"][dirs, h]
-        rd = c["eq_rd"][dirs, h]
-        have = c["eq_cnt"] > 0
-        ready = rd < now_end
-        bud3 = jnp.concatenate([budget, jnp.asarray([3e38], jnp.float32)])
-        bud_ok = bud3[dirs] > 0.0
-        pop = have & ready & bud_ok            # [3]
-        c["eq_head"] = (c["eq_head"] + pop) % cfg.eq_len
-        c["eq_cnt"] = c["eq_cnt"] - pop
-        spend = jnp.where(pop[:2], sz[:2].astype(jnp.float32) + ovh, 0.0)
-        budget = budget - spend
-        if R_res:
-            # ungated debt charge — see res_w_any above; the three
-            # directions' spends of one iteration subtract together
-            res_bud = res_bud - (
-                res_w_eg[:, fl] * jnp.where(pop, sz, 0)
-                .astype(jnp.float32)[None, :]).sum(1)
-        c["credits_used"] = c["credits_used"] - pop.sum().astype(jnp.int32)
-        # completion = transfer start + own serialization delay
-        ser = jnp.where(dirs < 2,
-                        sz.astype(jnp.float32) / bpc[jnp.minimum(dirs, 1)],
-                        0.0)
-        comp_time = jnp.maximum(rd, now) + ser.astype(jnp.int32)
-        lat = comp_time - at
-        # record (scratch slot comp_cap for non-pops)
-        base = c["comp_n"]
-        offs = jnp.cumsum(pop.astype(jnp.int32)) - pop.astype(jnp.int32)
-        idx = jnp.where(pop, (base + offs) % cfg.comp_cap, cfg.comp_cap)
-        c["comp_fl"] = c["comp_fl"].at[idx].set(fl)
-        c["comp_lat"] = c["comp_lat"].at[idx].set(lat)
-        c["comp_t"] = c["comp_t"].at[idx].set(comp_time)
-        c["comp_sz"] = c["comp_sz"].at[idx].set(isz)
-        c["comp_n"] = base + pop.sum().astype(jnp.int32)
-        # per-flow counters (SLO accounting is on ingress payload bytes,
-        # as the paper's traffic generator measures); scatter-adds
-        # accumulate duplicate flow ids across the three directions.
-        c["c_done_msgs"] = c["c_done_msgs"].at[fl].add(pop.astype(jnp.int32))
-        lo = c["c_done_b_lo"].at[fl].add(jnp.where(pop, isz, 0))
-        c["c_done_b_hi"] = c["c_done_b_hi"] + (lo >> 20)
-        c["c_done_b_lo"] = lo & 0xFFFFF
-        c["c_lat_sum"] = c["c_lat_sum"].at[fl].add(
-            jnp.where(pop, lat.astype(jnp.float32), 0.0))
-        return c, budget, res_bud
+            def vec_srv(c, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end):
+                n_start = s_ok.astype(jnp.int32).sum(1)
+                lrow = jnp.where(s_ok, ia[:, None], A)   # OOB rows are dropped
+                c["lanes"] = c["lanes"].at[lrow, si].set(s_end, mode="drop")
+                c["aq_head"] = (c["aq_head"] + n_start) % cfg.aq_len
+                c["aq_cnt"] = c["aq_cnt"] - n_start
+                c["aq_bytes"] = c["aq_bytes"] - jnp.where(s_ok, s_sz, 0).sum(1)
+                # host-processing delay: closed-form LCG draw for *active*
+                # iteration i = k*ac_n + a (padded accel rows draw nothing),
+                # bitwise-equal to the sequential per-step update of a run
+                # with only the active accelerators
+                powv, sumv = _lcg_tables(A * Ks)
+                it = jnp.minimum(kk[None, :] * ac_n + ia[:, None],
+                                 A * Ks - 1)                     # [A, Ks]
+                r = c["rng"] * jnp.asarray(powv)[it] + jnp.asarray(sumv)[it]
+                adv = jnp.maximum(ac_n * Ks - 1, 0)
+                c["rng"] = jnp.where(sw, c["rng"] * jnp.asarray(powv)[adv]
+                                     + jnp.asarray(sumv)[adv], c["rng"])
+                u = (jnp.abs(r) % 65536).astype(jnp.float32) / 65536.0
+                hostd = jnp.where(sw, args["sw_delay"]
+                                  + (u ** 4) * args["sw_jit"], jnp.float32(0.0))
+                ready = (s_end + hostd).astype(jnp.int32)
+                # egress pushes in sequential iteration order (k-major flatten)
+                flat = lambda x: x.T.reshape(-1)                 # noqa: E731
+                okf = flat(s_ok)
+                d = fl_eg_dir[flat(s_fl)]
+                Mt = A * Ks
+                lt = jnp.tril(jnp.ones((Mt, Mt), jnp.int32), -1)
+                same_d = (d[None, :] == d[:, None]).astype(jnp.int32)
+                rank = jnp.sum(lt * same_d * okf.astype(jnp.int32)[None, :],
+                               axis=1)
+                okq = okf & (c["eq_cnt"][d] + rank < cfg.eq_len)
+                eslot = (c["eq_head"][d] + c["eq_cnt"][d] + rank) % cfg.eq_len
+                drow = jnp.where(okq, d, 3)           # OOB rows are dropped
+                c["eq_sz"] = c["eq_sz"].at[drow, eslot].set(
+                    jnp.maximum(flat(s_esz).astype(jnp.int32), 1), mode="drop")
+                c["eq_isz"] = c["eq_isz"].at[drow, eslot].set(
+                    flat(s_sz), mode="drop")
+                c["eq_fl"] = c["eq_fl"].at[drow, eslot].set(
+                    flat(s_fl), mode="drop")
+                c["eq_at"] = c["eq_at"].at[drow, eslot].set(
+                    flat(s_at), mode="drop")
+                c["eq_rd"] = c["eq_rd"].at[drow, eslot].set(
+                    flat(ready), mode="drop")
+                c["eq_cnt"] = c["eq_cnt"] + jnp.zeros((3,), jnp.int32) \
+                    .at[d].add(okq.astype(jnp.int32))
+                return c
 
-    if cfg.stage_fast:
-        # Vectorized egress: gather the next k_eg ring entries of every
-        # direction at once.  Pops per direction are a prefix (a head that
-        # is not ready / not funded stays at the head for the rest of the
-        # tick), so one cumulative-AND replaces the k_eg-iteration loop.
-        # The budget chain is evaluated in the exact sequential subtraction
-        # order to keep the carried link debt bitwise-identical.
-        Ke = cfg.k_eg
-        jj = jnp.arange(Ke, dtype=jnp.int32)
-        eh = (carry["eq_head"][:, None] + jj[None, :]) % cfg.eq_len
-        e_sz = carry["eq_sz"][dirs[:, None], eh]
-        e_isz = carry["eq_isz"][dirs[:, None], eh]
-        e_fl = carry["eq_fl"][dirs[:, None], eh]
-        e_at = carry["eq_at"][dirs[:, None], eh]
-        e_rd = carry["eq_rd"][dirs[:, None], eh]
-        e_have = jj[None, :] < carry["eq_cnt"][:, None]
-        e_ready = e_rd < now_end
-        spend_mat = jnp.where((dirs < 2)[:, None],
-                              e_sz.astype(jnp.float32) + ovh, 0.0)
-        pops, prev = [], jnp.ones((3,), bool)
-        b_run = budget
-        r_run = res_bud
-        for j in range(Ke):
-            bud_ok = jnp.concatenate(
-                [b_run, jnp.asarray([3e38], jnp.float32)]) > 0.0
-            pop_j = prev & e_have[:, j] & e_ready[:, j] & bud_ok
-            b_run = b_run - jnp.where(pop_j[:2], spend_mat[:2, j], 0.0)
+            carry = jax.lax.cond(srv_fast, vec_srv, lambda c, *_a: seq_srv(c),
+                                 carry, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end)
+        else:
+            carry = seq_srv(carry)
+
+    with jax.named_scope("egress"):
+        # -- 6. egress link + completions ----------------------------------
+        dirs = jnp.arange(3, dtype=jnp.int32)
+
+        def eg_body(_, st):
+            c, budget, res_bud = st
+            h = c["eq_head"]                       # [3]
+            sz = c["eq_sz"][dirs, h]
+            isz = c["eq_isz"][dirs, h]
+            fl = c["eq_fl"][dirs, h]
+            at = c["eq_at"][dirs, h]
+            rd = c["eq_rd"][dirs, h]
+            have = c["eq_cnt"] > 0
+            ready = rd < now_end
+            bud3 = jnp.concatenate([budget, jnp.asarray([3e38], jnp.float32)])
+            bud_ok = bud3[dirs] > 0.0
+            pop = have & ready & bud_ok            # [3]
+            c["eq_head"] = (c["eq_head"] + pop) % cfg.eq_len
+            c["eq_cnt"] = c["eq_cnt"] - pop
+            spend = jnp.where(pop[:2], sz[:2].astype(jnp.float32) + ovh, 0.0)
+            budget = budget - spend
             if R_res:
-                r_run = r_run - (
-                    res_w_eg[:, e_fl[:, j]]
-                    * jnp.where(pop_j, e_sz[:, j], 0)
+                # ungated debt charge — see res_w_any above; the three
+                # directions' spends of one iteration subtract together
+                res_bud = res_bud - (
+                    res_w_eg[:, fl] * jnp.where(pop, sz, 0)
                     .astype(jnp.float32)[None, :]).sum(1)
-            pops.append(pop_j)
-            prev = pop_j
-        pop = jnp.stack(pops, axis=1)                       # [3, Ke]
-        budget = b_run
-        res_bud = r_run
-        npop = pop.astype(jnp.int32).sum(1)
-        carry["eq_head"] = (carry["eq_head"] + npop) % cfg.eq_len
-        carry["eq_cnt"] = carry["eq_cnt"] - npop
-        carry["credits_used"] = carry["credits_used"] - npop.sum()
-        ser = jnp.where((dirs < 2)[:, None],
-                        e_sz.astype(jnp.float32)
-                        / bpc[jnp.minimum(dirs, 1)][:, None], 0.0)
-        comp_time = jnp.maximum(e_rd, now) + ser.astype(jnp.int32)
-        lat = comp_time - e_at
-        # completion ring in sequential (iteration, direction) order
-        flat = lambda x: x.T.reshape(-1)                    # noqa: E731
-        popf = flat(pop)
-        offs = jnp.cumsum(popf.astype(jnp.int32)) - popf.astype(jnp.int32)
-        idx = jnp.where(popf, (carry["comp_n"] + offs) % cfg.comp_cap,
-                        cfg.comp_cap)
-        carry["comp_fl"] = carry["comp_fl"].at[idx].set(flat(e_fl))
-        carry["comp_lat"] = carry["comp_lat"].at[idx].set(flat(lat))
-        carry["comp_t"] = carry["comp_t"].at[idx].set(flat(comp_time))
-        carry["comp_sz"] = carry["comp_sz"].at[idx].set(flat(e_isz))
-        carry["comp_n"] = carry["comp_n"] + npop.sum()
-        carry["c_done_msgs"] = carry["c_done_msgs"].at[flat(e_fl)].add(
-            popf.astype(jnp.int32))
-        lo = carry["c_done_b_lo"].at[flat(e_fl)].add(
-            jnp.where(popf, flat(e_isz), 0))
-        carry["c_done_b_hi"] = carry["c_done_b_hi"] + (lo >> 20)
-        carry["c_done_b_lo"] = lo & 0xFFFFF
-        carry["c_lat_sum"] = carry["c_lat_sum"].at[flat(e_fl)].add(
-            jnp.where(popf, flat(lat).astype(jnp.float32), 0.0))
-    else:
-        carry, budget, res_bud = _fori(cfg.k_eg, eg_body,
-                                       (carry, budget, res_bud))
+            c["credits_used"] = c["credits_used"] - pop.sum().astype(jnp.int32)
+            # completion = transfer start + own serialization delay
+            ser = jnp.where(dirs < 2,
+                            sz.astype(jnp.float32) / bpc[jnp.minimum(dirs, 1)],
+                            0.0)
+            comp_time = jnp.maximum(rd, now) + ser.astype(jnp.int32)
+            lat = comp_time - at
+            # record (scratch slot comp_cap for non-pops)
+            base = c["comp_n"]
+            offs = jnp.cumsum(pop.astype(jnp.int32)) - pop.astype(jnp.int32)
+            idx = jnp.where(pop, (base + offs) % cfg.comp_cap, cfg.comp_cap)
+            c["comp_fl"] = c["comp_fl"].at[idx].set(fl)
+            c["comp_lat"] = c["comp_lat"].at[idx].set(lat)
+            c["comp_t"] = c["comp_t"].at[idx].set(comp_time)
+            c["comp_sz"] = c["comp_sz"].at[idx].set(isz)
+            c["comp_n"] = base + pop.sum().astype(jnp.int32)
+            # per-flow counters (SLO accounting is on ingress payload bytes,
+            # as the paper's traffic generator measures); scatter-adds
+            # accumulate duplicate flow ids across the three directions.
+            c["c_done_msgs"] = c["c_done_msgs"].at[fl].add(pop.astype(jnp.int32))
+            lo = c["c_done_b_lo"].at[fl].add(jnp.where(pop, isz, 0))
+            c["c_done_b_hi"] = c["c_done_b_hi"] + (lo >> 20)
+            c["c_done_b_lo"] = lo & 0xFFFFF
+            c["c_lat_sum"] = c["c_lat_sum"].at[fl].add(
+                jnp.where(pop, lat.astype(jnp.float32), 0.0))
+            return c, budget, res_bud
 
-    # Positive leftover budget is lost (a link cannot save idle time);
-    # negative budget (serialization debt of in-flight messages) carries.
-    carry["lres"] = jnp.minimum(budget, 0.0)
-    if R_res:
-        # each axis is a token bucket: unused budget carries up to the
-        # axis' burst depth (burst 0 reproduces the link's lose-idle-time
-        # semantics); debt always carries
-        carry["res_res"] = jnp.minimum(res_bud, args["res_burst"])
+        if cfg.stage_fast:
+            # Vectorized egress: gather the next k_eg ring entries of every
+            # direction at once.  Pops per direction are a prefix (a head that
+            # is not ready / not funded stays at the head for the rest of the
+            # tick), so one cumulative-AND replaces the k_eg-iteration loop.
+            # The budget chain is evaluated in the exact sequential subtraction
+            # order to keep the carried link debt bitwise-identical.
+            Ke = cfg.k_eg
+            jj = jnp.arange(Ke, dtype=jnp.int32)
+            eh = (carry["eq_head"][:, None] + jj[None, :]) % cfg.eq_len
+            e_sz = carry["eq_sz"][dirs[:, None], eh]
+            e_isz = carry["eq_isz"][dirs[:, None], eh]
+            e_fl = carry["eq_fl"][dirs[:, None], eh]
+            e_at = carry["eq_at"][dirs[:, None], eh]
+            e_rd = carry["eq_rd"][dirs[:, None], eh]
+            e_have = jj[None, :] < carry["eq_cnt"][:, None]
+            e_ready = e_rd < now_end
+            spend_mat = jnp.where((dirs < 2)[:, None],
+                                  e_sz.astype(jnp.float32) + ovh, 0.0)
+            pops, prev = [], jnp.ones((3,), bool)
+            b_run = budget
+            r_run = res_bud
+            for j in range(Ke):
+                bud_ok = jnp.concatenate(
+                    [b_run, jnp.asarray([3e38], jnp.float32)]) > 0.0
+                pop_j = prev & e_have[:, j] & e_ready[:, j] & bud_ok
+                b_run = b_run - jnp.where(pop_j[:2], spend_mat[:2, j], 0.0)
+                if R_res:
+                    r_run = r_run - (
+                        res_w_eg[:, e_fl[:, j]]
+                        * jnp.where(pop_j, e_sz[:, j], 0)
+                        .astype(jnp.float32)[None, :]).sum(1)
+                pops.append(pop_j)
+                prev = pop_j
+            pop = jnp.stack(pops, axis=1)                       # [3, Ke]
+            budget = b_run
+            res_bud = r_run
+            npop = pop.astype(jnp.int32).sum(1)
+            carry["eq_head"] = (carry["eq_head"] + npop) % cfg.eq_len
+            carry["eq_cnt"] = carry["eq_cnt"] - npop
+            carry["credits_used"] = carry["credits_used"] - npop.sum()
+            ser = jnp.where((dirs < 2)[:, None],
+                            e_sz.astype(jnp.float32)
+                            / bpc[jnp.minimum(dirs, 1)][:, None], 0.0)
+            comp_time = jnp.maximum(e_rd, now) + ser.astype(jnp.int32)
+            lat = comp_time - e_at
+            # completion ring in sequential (iteration, direction) order
+            flat = lambda x: x.T.reshape(-1)                    # noqa: E731
+            popf = flat(pop)
+            offs = jnp.cumsum(popf.astype(jnp.int32)) - popf.astype(jnp.int32)
+            idx = jnp.where(popf, (carry["comp_n"] + offs) % cfg.comp_cap,
+                            cfg.comp_cap)
+            carry["comp_fl"] = carry["comp_fl"].at[idx].set(flat(e_fl))
+            carry["comp_lat"] = carry["comp_lat"].at[idx].set(flat(lat))
+            carry["comp_t"] = carry["comp_t"].at[idx].set(flat(comp_time))
+            carry["comp_sz"] = carry["comp_sz"].at[idx].set(flat(e_isz))
+            carry["comp_n"] = carry["comp_n"] + npop.sum()
+            carry["c_done_msgs"] = carry["c_done_msgs"].at[flat(e_fl)].add(
+                popf.astype(jnp.int32))
+            lo = carry["c_done_b_lo"].at[flat(e_fl)].add(
+                jnp.where(popf, flat(e_isz), 0))
+            carry["c_done_b_hi"] = carry["c_done_b_hi"] + (lo >> 20)
+            carry["c_done_b_lo"] = lo & 0xFFFFF
+            carry["c_lat_sum"] = carry["c_lat_sum"].at[flat(e_fl)].add(
+                jnp.where(popf, flat(lat).astype(jnp.float32), 0.0))
+        else:
+            carry, budget, res_bud = _fori(cfg.k_eg, eg_body,
+                                           (carry, budget, res_bud))
+
+        # Positive leftover budget is lost (a link cannot save idle time);
+        # negative budget (serialization debt of in-flight messages) carries.
+        carry["lres"] = jnp.minimum(budget, 0.0)
+        if R_res:
+            # each axis is a token bucket: unused budget carries up to the
+            # axis' burst depth (burst 0 reproduces the link's lose-idle-time
+            # semantics); debt always carries
+            carry["res_res"] = jnp.minimum(res_bud, args["res_burst"])
     return carry
 
 
@@ -1239,158 +1246,160 @@ def run_window_batch(flows: FlowSet | Sequence[FlowSet],
     every other lane keeps its position, so a resumed carry never needs a
     re-pack or a recompile).  Without it, masks are the usual active
     prefix derived from each element's flow count."""
-    if not hasattr(arr_t, "ndim"):       # nested python lists
-        arr_t = np.asarray(arr_t)
-        arr_sz = np.asarray(arr_sz)
-    if arr_t.ndim != 3:
-        raise ValueError(
-            f"arr_t must be [B, N, M] (got ndim={arr_t.ndim}) — "
-            "see stack_arrivals()")
-    B = arr_t.shape[0]
-    flows_l = _as_list(flows, B)
-    accels_l = _as_list(accels, B)
-    links_l = _as_list(link, B)
-    cfgs_l = _as_list(cfg, B)
-    if tb_states is None and carry is None:
-        raise ValueError("tb_states=None is only valid when resuming a "
-                         "carry (initial registers are required)")
-    if not (len(accels_l) == B and len(links_l) == B
-            and (tb_states is None or len(tb_states) == B)
-            and len(flows_l) == B and len(cfgs_l) == B):
-        raise ValueError(
-            f"batch size mismatch: arr_t has B={B} but "
-            f"flows={len(flows_l)}, accels={len(accels_l)}, "
-            f"links={len(links_l)}, "
-            f"tb_states={len(tb_states or [])}, cfgs={len(cfgs_l)}")
-    cfg0 = cfgs_l[0]
-    if any(_static_cfg(c) != _static_cfg(cfg0) for c in cfgs_l[1:]):
-        raise ValueError(
-            "batched SimConfigs may differ only in traced fields "
-            f"{TRACED_CFG_FIELDS}")
-    for c in cfgs_l[1:]:
-        _check_modes(c)    # element 0 is checked by _pack_args below
-    a_max = max(a.n for a in accels_l)
-    padded_l = [pad_accel_table(a, a_max) for a in accels_l]
+    with jax.profiler.TraceAnnotation("arcus.engine.prepare"):
+        if not hasattr(arr_t, "ndim"):       # nested python lists
+            arr_t = np.asarray(arr_t)
+            arr_sz = np.asarray(arr_sz)
+        if arr_t.ndim != 3:
+            raise ValueError(
+                f"arr_t must be [B, N, M] (got ndim={arr_t.ndim}) — "
+                "see stack_arrivals()")
+        B = arr_t.shape[0]
+        flows_l = _as_list(flows, B)
+        accels_l = _as_list(accels, B)
+        links_l = _as_list(link, B)
+        cfgs_l = _as_list(cfg, B)
+        if tb_states is None and carry is None:
+            raise ValueError("tb_states=None is only valid when resuming a "
+                             "carry (initial registers are required)")
+        if not (len(accels_l) == B and len(links_l) == B
+                and (tb_states is None or len(tb_states) == B)
+                and len(flows_l) == B and len(cfgs_l) == B):
+            raise ValueError(
+                f"batch size mismatch: arr_t has B={B} but "
+                f"flows={len(flows_l)}, accels={len(accels_l)}, "
+                f"links={len(links_l)}, "
+                f"tb_states={len(tb_states or [])}, cfgs={len(cfgs_l)}")
+        cfg0 = cfgs_l[0]
+        if any(_static_cfg(c) != _static_cfg(cfg0) for c in cfgs_l[1:]):
+            raise ValueError(
+                "batched SimConfigs may differ only in traced fields "
+                f"{TRACED_CFG_FIELDS}")
+        for c in cfgs_l[1:]:
+            _check_modes(c)    # element 0 is checked by _pack_args below
+        a_max = max(a.n for a in accels_l)
+        padded_l = [pad_accel_table(a, a_max) for a in accels_l]
 
-    n_res = len(getattr(links_l[0], "resources", ()))
-    if any(len(getattr(l, "resources", ())) != n_res
-           for l in links_l[1:]):
-        raise ValueError(
-            "batched LinkSpecs must all carry the same number of resource "
-            "axes (resource tables are a shared traced shape; a huge-"
-            "capacity axis is inert if an element needs fewer)")
+        n_res = len(getattr(links_l[0], "resources", ()))
+        if any(len(getattr(l, "resources", ())) != n_res
+               for l in links_l[1:]):
+            raise ValueError(
+                "batched LinkSpecs must all carry the same number of resource "
+                "axes (resource tables are a shared traced shape; a huge-"
+                "capacity axis is inert if an element needs fewer)")
 
-    n_max = max(f.n for f in flows_l)
-    if arr_t.shape[1] != n_max:
-        raise ValueError(
-            f"arr_t flow axis {arr_t.shape[1]} != n_flows_max {n_max} — "
-            "see stack_arrivals()")
+        n_max = max(f.n for f in flows_l)
+        if arr_t.shape[1] != n_max:
+            raise ValueError(
+                f"arr_t flow axis {arr_t.shape[1]} != n_flows_max {n_max} — "
+                "see stack_arrivals()")
 
-    if fl_masks is not None and len(fl_masks) != B:
-        raise ValueError(
-            f"fl_masks must have one mask per element (got {len(fl_masks)} "
-            f"for B={B})")
-    flows_batched = (fl_masks is not None
-                     or (isinstance(flows, (list, tuple))
-                         and (len(set(f.n for f in flows_l)) > 1
-                              or any(f is not flows_l[0] for f in flows_l))))
-    accel_batched = isinstance(accels, (list, tuple))
-    link_batched = isinstance(link, (list, tuple))
-    cfg_batched = (isinstance(cfg, (list, tuple))
-                   and any(c != cfg0 for c in cfgs_l[1:]))
-    stall_np = None if stall_mask is None else np.asarray(stall_mask, bool)
-    stall_batched = stall_np is not None and stall_np.ndim == 2
+        if fl_masks is not None and len(fl_masks) != B:
+            raise ValueError(
+                f"fl_masks must have one mask per element (got {len(fl_masks)} "
+                f"for B={B})")
+        flows_batched = (fl_masks is not None
+                         or (isinstance(flows, (list, tuple))
+                             and (len(set(f.n for f in flows_l)) > 1
+                                  or any(f is not flows_l[0] for f in flows_l))))
+        accel_batched = isinstance(accels, (list, tuple))
+        link_batched = isinstance(link, (list, tuple))
+        cfg_batched = (isinstance(cfg, (list, tuple))
+                       and any(c != cfg0 for c in cfgs_l[1:]))
+        stall_np = None if stall_mask is None else np.asarray(stall_mask, bool)
+        stall_batched = stall_np is not None and stall_np.ndim == 2
 
-    # pack with tiny placeholders for the per-element entries (the real
-    # batched trace / stall arrays replace them below) so a multi-megabyte
-    # single-element trace is never uploaded just to be discarded
-    ph = np.zeros((n_max, 1), np.int32)
-    flows0 = flows_l[0] if flows_l[0].n == n_max else flows_l[
-        int(np.argmax([f.n for f in flows_l]))]
-    args = _pack_args(flows0, padded_l[0], links_l[0], cfg0,
-                      ph, ph, None, t0_ticks)
-    axes = {k: None for k in args}
-    args["arr_t"] = jnp.asarray(arr_t, jnp.int32)
-    args["arr_sz"] = jnp.asarray(arr_sz, jnp.int32)
-    axes["arr_t"] = axes["arr_sz"] = 0
-    if flows_batched:
-        per_el = [_flow_args(f, n_max) for f in flows_l]
-        if fl_masks is not None:
-            for p, m in zip(per_el, fl_masks):
-                m = np.asarray(m, bool)
-                if m.shape != (n_max,):
-                    raise ValueError(
-                        f"fl_masks entries must be [{n_max}] bool "
-                        f"(got shape {m.shape})")
-                p["fl_mask"] = m
-        for k in per_el[0]:
-            args[k] = jnp.stack([jnp.asarray(p[k]) for p in per_el])
-            axes[k] = 0
-    if cfg_batched:
-        args["mode"] = jnp.asarray([c.shaping for c in cfgs_l], jnp.int32)
-        args["arb"] = jnp.asarray([c.arbiter for c in cfgs_l], jnp.int32)
-        args["sw_delay"] = jnp.asarray(
-            [c.sw_host_delay_cycles for c in cfgs_l], jnp.float32)
-        args["sw_jit"] = jnp.asarray(
-            [c.sw_jitter_cycles for c in cfgs_l], jnp.float32)
-        axes["mode"] = axes["arb"] = axes["sw_delay"] = axes["sw_jit"] = 0
-    if accel_batched:
-        args["svc_tab"] = jnp.stack(
-            [jnp.asarray(a.service_cycles, jnp.float32) for a in padded_l])
-        args["eg_tab"] = jnp.stack(
-            [jnp.asarray(a.egress_bytes, jnp.float32) for a in padded_l])
-        args["ac_mask"] = jnp.stack(
-            [jnp.asarray(_accel_mask(a), bool) for a in padded_l])
-        axes["svc_tab"] = axes["eg_tab"] = axes["ac_mask"] = 0
-    if link_batched:
-        args["bpc"] = jnp.asarray([l.bytes_per_cycle() for l in links_l],
-                                  jnp.float32)
-        args["ovh"] = jnp.asarray(
-            [l.msg_overhead_bytes for l in links_l], jnp.float32)
-        args["credits"] = jnp.asarray([l.credits for l in links_l], jnp.int32)
-        axes["bpc"] = axes["ovh"] = axes["credits"] = 0
-        if n_res:
-            args["res_cap"] = jnp.asarray(
-                np.stack([l.resource_caps_per_cycle() for l in links_l]),
-                jnp.float32)
-            args["res_burst"] = jnp.asarray(
-                np.stack([l.resource_burst_bytes() for l in links_l]),
-                jnp.float32)
-            axes["res_cap"] = axes["res_burst"] = 0
-    if n_res and (flows_batched or accel_batched or link_batched):
-        # demand coefficients depend on flows x accels x link axes; batch
-        # the [R-1, n_max] tables whenever any of the three is per-element
-        tabs = [_resource_tables(flows_l[b], padded_l[b], links_l[b], n_max)
-                for b in range(B)]
-        args["res_w_in"] = jnp.asarray(np.stack([t[0] for t in tabs]),
-                                       jnp.float32)
-        args["res_w_eg"] = jnp.asarray(np.stack([t[1] for t in tabs]),
-                                       jnp.float32)
-        axes["res_w_in"] = axes["res_w_eg"] = 0
-    if stall_np is not None:
-        args["stall"] = jnp.asarray(
-            _window_stall(stall_np, cfg0, t0_ticks), bool)
-        axes["stall"] = 0 if stall_batched else None
+        # pack with tiny placeholders for the per-element entries (the real
+        # batched trace / stall arrays replace them below) so a multi-megabyte
+        # single-element trace is never uploaded just to be discarded
+        ph = np.zeros((n_max, 1), np.int32)
+        flows0 = flows_l[0] if flows_l[0].n == n_max else flows_l[
+            int(np.argmax([f.n for f in flows_l]))]
+        args = _pack_args(flows0, padded_l[0], links_l[0], cfg0,
+                          ph, ph, None, t0_ticks)
+        axes = {k: None for k in args}
+        args["arr_t"] = jnp.asarray(arr_t, jnp.int32)
+        args["arr_sz"] = jnp.asarray(arr_sz, jnp.int32)
+        axes["arr_t"] = axes["arr_sz"] = 0
+        if flows_batched:
+            per_el = [_flow_args(f, n_max) for f in flows_l]
+            if fl_masks is not None:
+                for p, m in zip(per_el, fl_masks):
+                    m = np.asarray(m, bool)
+                    if m.shape != (n_max,):
+                        raise ValueError(
+                            f"fl_masks entries must be [{n_max}] bool "
+                            f"(got shape {m.shape})")
+                    p["fl_mask"] = m
+            for k in per_el[0]:
+                args[k] = jnp.stack([jnp.asarray(p[k]) for p in per_el])
+                axes[k] = 0
+        if cfg_batched:
+            args["mode"] = jnp.asarray([c.shaping for c in cfgs_l], jnp.int32)
+            args["arb"] = jnp.asarray([c.arbiter for c in cfgs_l], jnp.int32)
+            args["sw_delay"] = jnp.asarray(
+                [c.sw_host_delay_cycles for c in cfgs_l], jnp.float32)
+            args["sw_jit"] = jnp.asarray(
+                [c.sw_jitter_cycles for c in cfgs_l], jnp.float32)
+            axes["mode"] = axes["arb"] = axes["sw_delay"] = axes["sw_jit"] = 0
+        if accel_batched:
+            args["svc_tab"] = jnp.stack(
+                [jnp.asarray(a.service_cycles, jnp.float32) for a in padded_l])
+            args["eg_tab"] = jnp.stack(
+                [jnp.asarray(a.egress_bytes, jnp.float32) for a in padded_l])
+            args["ac_mask"] = jnp.stack(
+                [jnp.asarray(_accel_mask(a), bool) for a in padded_l])
+            axes["svc_tab"] = axes["eg_tab"] = axes["ac_mask"] = 0
+        if link_batched:
+            args["bpc"] = jnp.asarray([l.bytes_per_cycle() for l in links_l],
+                                      jnp.float32)
+            args["ovh"] = jnp.asarray(
+                [l.msg_overhead_bytes for l in links_l], jnp.float32)
+            args["credits"] = jnp.asarray([l.credits for l in links_l], jnp.int32)
+            axes["bpc"] = axes["ovh"] = axes["credits"] = 0
+            if n_res:
+                args["res_cap"] = jnp.asarray(
+                    np.stack([l.resource_caps_per_cycle() for l in links_l]),
+                    jnp.float32)
+                args["res_burst"] = jnp.asarray(
+                    np.stack([l.resource_burst_bytes() for l in links_l]),
+                    jnp.float32)
+                axes["res_cap"] = axes["res_burst"] = 0
+        if n_res and (flows_batched or accel_batched or link_batched):
+            # demand coefficients depend on flows x accels x link axes; batch
+            # the [R-1, n_max] tables whenever any of the three is per-element
+            tabs = [_resource_tables(flows_l[b], padded_l[b], links_l[b], n_max)
+                    for b in range(B)]
+            args["res_w_in"] = jnp.asarray(np.stack([t[0] for t in tabs]),
+                                           jnp.float32)
+            args["res_w_eg"] = jnp.asarray(np.stack([t[1] for t in tabs]),
+                                           jnp.float32)
+            axes["res_w_in"] = axes["res_w_eg"] = 0
+        if stall_np is not None:
+            args["stall"] = jnp.asarray(
+                _window_stall(stall_np, cfg0, t0_ticks), bool)
+            axes["stall"] = 0 if stall_batched else None
 
-    if carry is None:
-        tb_padded = [pad_tb_state(tb_states[b], n_max) for b in range(B)]
-        carries = [init_carry(flows_l[b], padded_l[b], cfg0, tb_padded[b],
-                              n_flows=n_max, n_res=n_res)
-                   for b in range(B)]
-        carry = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *carries)
-    elif tb_states is not None:
-        # resumed fleet window: write only the per-element parameter
-        # "registers" (stacked [B, n_max] leaves), like run_window does;
-        # tb_states=None resumes without touching the registers
-        tb_padded = [pad_tb_state(tb_states[b], n_max) for b in range(B)]
-        stacked_tb = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *tb_padded)
-        carry = reconfigure_carry(carry, stacked_tb)
+        if carry is None:
+            tb_padded = [pad_tb_state(tb_states[b], n_max) for b in range(B)]
+            carries = [init_carry(flows_l[b], padded_l[b], cfg0, tb_padded[b],
+                                  n_flows=n_max, n_res=n_res)
+                       for b in range(B)]
+            carry = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *carries)
+        elif tb_states is not None:
+            # resumed fleet window: write only the per-element parameter
+            # "registers" (stacked [B, n_max] leaves), like run_window does;
+            # tb_states=None resumes without touching the registers
+            tb_padded = [pad_tb_state(tb_states[b], n_max) for b in range(B)]
+            stacked_tb = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *tb_padded)
+            carry = reconfigure_carry(carry, stacked_tb)
 
-    key = ("batch", _static_cfg(cfg0), B, _args_sig(args),
-           tuple(sorted(axes.items())))
-    run = _get_run(key, lambda: jax.jit(
-        jax.vmap(functools.partial(_run_core, _static_cfg(cfg0)),
-                 in_axes=(0, axes)),
-        donate_argnums=(0,)))
-    return run(carry, args)
+        key = ("batch", _static_cfg(cfg0), B, _args_sig(args),
+               tuple(sorted(axes.items())))
+        run = _get_run(key, lambda: jax.jit(
+            jax.vmap(functools.partial(_run_core, _static_cfg(cfg0)),
+                     in_axes=(0, axes)),
+            donate_argnums=(0,)))
+    with jax.profiler.TraceAnnotation("arcus.engine.dispatch"):
+        return run(carry, args)

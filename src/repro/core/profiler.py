@@ -32,6 +32,7 @@ import json
 import warnings
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.core import baselines, token_bucket as tb
@@ -438,40 +439,41 @@ def profile_contexts_multi(jobs: Sequence[tuple["ProfileTable",
     axis).  Entries are
     bitwise-identical to serial ``profile_context`` runs and are written
     into each job's own table.  Returns entries aligned with ``jobs``."""
-    _PROFILING_STATS["calls"] += 1
-    keys = [context_key(a.name, f) for _, a, f in jobs]
-    todo: dict[tuple[int, str], tuple["ProfileTable", str, AcceleratorSpec,
-                                      list]] = {}
-    for (table, accel, flows), key in zip(jobs, keys):
-        tk = (id(table), key)
-        if key not in table.entries and tk not in todo:
-            todo[tk] = (table, key, accel, flows)
-    groups: dict[tuple[int, int, float], list] = {}
-    for item in todo.values():
-        table = item[0]
-        groups.setdefault((table.n_ticks, table.tick_cycles, table.clock_hz),
-                          []).append(item)
-    for items in groups.values():
-        _PROFILING_STATS["sim_batches"] += 1
-        _PROFILING_STATS["contexts"] += len(items)
-        cfg = items[0][0]._cfg()
-        fsets, atabs, tbss, arrs, ns, links = [], [], [], [], [], []
-        for table, key, accel, flows in items:
-            specs = _context_specs(flows)
-            fset = FlowSet.build(specs)
-            ref = {i: accel.peak_gbps for i in range(len(specs))}
-            fsets.append(fset)
-            atabs.append(AccelTable.build([accel], table.clock_hz))
-            tbss.append(baselines.make_tb_state(
-                baselines.HOST_NO_TS,
-                [tb.TBParams(1, 1, 1)] * len(specs)))
-            arrs.append(gen_arrivals(fset, cfg, seed=seed,
-                                     load_ref_gbps=ref))
-            ns.append(len(specs))
-            links.append(table.link)
-        link_arg = links[0] if all(ln is links[0] for ln in links) else links
-        results = simulate_batch(fsets, atabs, link_arg, cfg, tbss,
-                                 *stack_arrivals(arrs))
-        for (table, key, a, f), res, n in zip(items, results, ns):
-            table._entry_from_result(key, res, n, a, f)
-    return [t.entries[k] for (t, _, _), k in zip(jobs, keys)]
+    with jax.profiler.TraceAnnotation("arcus.profile.contexts"):
+        _PROFILING_STATS["calls"] += 1
+        keys = [context_key(a.name, f) for _, a, f in jobs]
+        todo: dict[tuple[int, str], tuple["ProfileTable", str, AcceleratorSpec,
+                                          list]] = {}
+        for (table, accel, flows), key in zip(jobs, keys):
+            tk = (id(table), key)
+            if key not in table.entries and tk not in todo:
+                todo[tk] = (table, key, accel, flows)
+        groups: dict[tuple[int, int, float], list] = {}
+        for item in todo.values():
+            table = item[0]
+            groups.setdefault((table.n_ticks, table.tick_cycles, table.clock_hz),
+                              []).append(item)
+        for items in groups.values():
+            _PROFILING_STATS["sim_batches"] += 1
+            _PROFILING_STATS["contexts"] += len(items)
+            cfg = items[0][0]._cfg()
+            fsets, atabs, tbss, arrs, ns, links = [], [], [], [], [], []
+            for table, key, accel, flows in items:
+                specs = _context_specs(flows)
+                fset = FlowSet.build(specs)
+                ref = {i: accel.peak_gbps for i in range(len(specs))}
+                fsets.append(fset)
+                atabs.append(AccelTable.build([accel], table.clock_hz))
+                tbss.append(baselines.make_tb_state(
+                    baselines.HOST_NO_TS,
+                    [tb.TBParams(1, 1, 1)] * len(specs)))
+                arrs.append(gen_arrivals(fset, cfg, seed=seed,
+                                         load_ref_gbps=ref))
+                ns.append(len(specs))
+                links.append(table.link)
+            link_arg = links[0] if all(ln is links[0] for ln in links) else links
+            results = simulate_batch(fsets, atabs, link_arg, cfg, tbss,
+                                     *stack_arrivals(arrs))
+            for (table, key, a, f), res, n in zip(items, results, ns):
+                table._entry_from_result(key, res, n, a, f)
+        return [t.entries[k] for (t, _, _), k in zip(jobs, keys)]
