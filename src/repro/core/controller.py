@@ -157,7 +157,9 @@ class FleetController:
         self._envelopes: list[tuple[int, dict] | None] = \
             [None] * len(self.runtimes)   # per-server (version, envelopes)
         self.stats = {"admitted": 0, "rejected": 0, "departed": 0,
-                      "migrated": 0, "repacks": 0}
+                      "migrated": 0, "repacks": 0,
+                      # of the last run (see run's closing collection)
+                      "grant_fast_share": 0.0, "srv_fast_share": 0.0}
         self.last_events: list[dict] = []
 
     # ------------------------------------------------------------------
@@ -911,7 +913,13 @@ class FleetController:
         finally:
             self._in_run = False
         with jax.profiler.TraceAnnotation("arcus.fleet.collect"):
-            host = jax.device_get({k: carry[k] for k in sim._RESULT_KEYS})
+            host = jax.device_get({k: carry[k] for k in
+                                   sim._RESULT_KEYS + engine.FAST_TICK_KEYS})
+            # share of the run's ticks on which a stage ran its vectorized
+            # path alone (fleet-wide in the batched engine)
+            for name, k in zip(("grant_fast_share", "srv_fast_share"),
+                               engine.FAST_TICK_KEYS):
+                self.stats[name] = float(np.mean(host.pop(k))) / total_ticks
             t0_last, wcfg_last = windows[-1]
             results = []
             for b in range(B):

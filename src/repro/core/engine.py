@@ -35,7 +35,10 @@ mode words, accelerator/link tables and stall masks).  Flow sets with
 with ``fl_mask``: padded lanes never receive arrivals, are never eligible
 for grants, and the arbiter keys are computed modulo the *active* flow
 count, so every counter of an active lane is bitwise-identical to a serial
-unpadded run.
+unpadded run.  The vmap axis is named (``FLEET_AXIS``): where a tick stage
+chooses between its vectorized path and a sequential fallback, it chooses
+once for the fleet when every server qualifies, so the fallback runs only
+on ticks where some server needs it (``_fast_or_fallback``).
 
 Accelerator tables batch the same way: elements with *different accelerator
 counts* are padded to a shared ``n_accels_max`` (``pad_accel_table``) with a
@@ -208,6 +211,10 @@ def init_carry(flows: FlowSet, accels: AccelTable, cfg: SimConfig,
         comp_sz=jnp.zeros((cfg.comp_cap + 1,), jnp.int32),
         comp_n=jnp.zeros((), jnp.int32),
         rng=jnp.asarray(np.int32(0x1234567)),
+        # ticks on which the grant / service stage ran its vectorized path
+        # alone (see _fast_or_fallback)
+        c_grant_fast_ticks=jnp.zeros((), jnp.int32),
+        c_srv_fast_ticks=jnp.zeros((), jnp.int32),
     )
 
 
@@ -536,7 +543,36 @@ def _interp_mat(table, msg_bytes_f32):
     return interp_grid(table, a_grid, msg_bytes_f32)
 
 
-def _tick(cfg: SimConfig, args: dict, carry: dict, t):
+#: vmap axis name of the batched engine's fleet axis (``run_window_batch``)
+FLEET_AXIS = "fleet"
+
+#: carry counters of the ticks a stage ran its vectorized path alone
+FAST_TICK_KEYS = ("c_grant_fast_ticks", "c_srv_fast_ticks")
+
+
+def _fast_or_fallback(axis, pred, fast, slow, *operands):
+    """``lax.cond(pred, fast, slow, *operands)``, chosen once for the fleet.
+
+    Under ``vmap`` a per-server predicate is batched, and a batched cond
+    lowers to a select that runs both branches.  With the fleet's
+    ``axis`` name, the predicate is first reduced over the fleet: on a
+    tick where every server takes ``fast``, an unbatched cond runs it
+    alone; otherwise each server takes its own branch through the
+    per-server select.  Either way a server's result comes from the
+    branch its own predicate picks, so results are bitwise those of the
+    serial engine (``axis`` None: a plain cond).  Returns the result and
+    whether ``fast`` ran alone on this tick."""
+    if axis is None:
+        return jax.lax.cond(pred, fast, slow, *operands), pred
+    all_fast = (jax.lax.psum(pred.astype(jnp.int32), axis)
+                == jax.lax.axis_size(axis))
+    out = jax.lax.cond(all_fast, fast,
+                       lambda *o: jax.lax.cond(pred, fast, slow, *o),
+                       *operands)
+    return out, all_fast
+
+
+def _tick(cfg: SimConfig, args: dict, carry: dict, t, axis=None):
     arr_t, arr_sz = args["arr_t"], args["arr_sz"]
     fl_accel, fl_in_dir = args["fl_accel"], args["fl_in_dir"]
     fl_eg_dir, fl_eg_full = args["fl_eg_dir"], args["fl_eg_full"]
@@ -791,14 +827,11 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
                             | jnp.all(~elig | (carry["q_cnt"] <= 1)))
             fast_pred = ok_all & regrant_safe & arb_rr
 
-            # Under vmap (run_window_batch) this cond lowers to a select that
-            # evaluates BOTH branches per lane.  That waste is accepted on
-            # purpose: batched and serial runs then share the exact per-lane
-            # computation, which is what guarantees simulate_batch() counters
-            # bitwise-match serial simulate() — stripping the fast path from
-            # batch engines would instead rely on fast==sequential holding to
-            # the last float ulp.  Callers who want a leaner batch engine can
-            # set SimConfig.grant_fast=False on both sides.
+            # Batched, the branch is chosen once per tick for the whole fleet
+            # (_fast_or_fallback): the sequential loop runs only on ticks where
+            # some server needs it, and each server keeps the branch its own
+            # predicate picks, so simulate_batch() counters stay bitwise those
+            # of serial simulate() without relying on fast==sequential.
             def vec_grants(c, budget, res_bud, order, valid, vi, csz, cat,
                            ccost, cdir, d01, cacc, spend, cnt_before):
                 c["tb"] = c["tb"]._replace(
@@ -840,10 +873,12 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
                 c["c_adm_b_lo"] = lo & 0xFFFFF
                 return c, budget, res_bud
 
-            carry, budget, res_bud = jax.lax.cond(
-                fast_pred, vec_grants, seq_grants,
+            (carry, budget, res_bud), alone = _fast_or_fallback(
+                axis, fast_pred, vec_grants, seq_grants,
                 carry, budget, res_bud, order, valid, vi, csz, cat, ccost,
                 cdir, d01, cacc, spend, cnt_before)
+            carry["c_grant_fast_ticks"] = (carry["c_grant_fast_ticks"]
+                                           + alone.astype(jnp.int32))
         else:
             carry, budget, res_bud = seq_grants(carry, budget, res_bud)
 
@@ -993,8 +1028,11 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
                     .at[d].add(okq.astype(jnp.int32))
                 return c
 
-            carry = jax.lax.cond(srv_fast, vec_srv, lambda c, *_a: seq_srv(c),
-                                 carry, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end)
+            carry, alone = _fast_or_fallback(
+                axis, srv_fast, vec_srv, lambda c, *_a: seq_srv(c),
+                carry, s_ok, si, s_sz, s_fl, s_at, s_esz, s_end)
+            carry["c_srv_fast_ticks"] = (carry["c_srv_fast_ticks"]
+                                         + alone.astype(jnp.int32))
         else:
             carry = seq_srv(carry)
 
@@ -1132,9 +1170,9 @@ def _tick(cfg: SimConfig, args: dict, carry: dict, t):
     return carry
 
 
-def _run_core(cfg: SimConfig, carry: dict, args: dict) -> dict:
+def _run_core(cfg: SimConfig, carry: dict, args: dict, axis=None) -> dict:
     xs = args["t0"] + jnp.arange(cfg.n_ticks, dtype=jnp.int32)
-    carry, _ = jax.lax.scan(lambda c, t: (_tick(cfg, args, c, t), None),
+    carry, _ = jax.lax.scan(lambda c, t: (_tick(cfg, args, c, t, axis), None),
                             carry, xs)
     return carry
 
@@ -1398,8 +1436,9 @@ def run_window_batch(flows: FlowSet | Sequence[FlowSet],
         key = ("batch", _static_cfg(cfg0), B, _args_sig(args),
                tuple(sorted(axes.items())))
         run = _get_run(key, lambda: jax.jit(
-            jax.vmap(functools.partial(_run_core, _static_cfg(cfg0)),
-                     in_axes=(0, axes)),
+            jax.vmap(functools.partial(_run_core, _static_cfg(cfg0),
+                                       axis=FLEET_AXIS),
+                     in_axes=(0, axes), axis_name=FLEET_AXIS),
             donate_argnums=(0,)))
     with jax.profiler.TraceAnnotation("arcus.engine.dispatch"):
         return run(carry, args)

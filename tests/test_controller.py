@@ -482,3 +482,21 @@ def test_controller_rejects_bad_events():
         ctrl.depart(42)
     with pytest.raises(ValueError, match="fleet-unique"):
         ctrl.admit(_spec(0, 1.0))
+
+
+def test_run_reports_fast_path_shares():
+    """``stats`` reports the share of a run's ticks on which the grant and
+    service stages ran their vectorized paths alone, fleet-wide.  SHA1-HMAC
+    and AES-128-CBC services outlast an 8-cycle tick, so no lane chains and
+    the service stage never needs its sequential fallback."""
+    rts = _mk_fleet((["sha1_hmac", "aes128_cbc"],) * 2)
+    for rt in rts:
+        assert rt.register(_spec(0, 2.0, msg=64, load=0.3))
+        assert rt.register(_spec(1, 4.0, accel_id=1, msg=256, load=0.3))
+    ctrl = FleetController(rts)
+    assert ctrl.stats["srv_fast_share"] == ctrl.stats["grant_fast_share"] == 0
+    ctrl.run(total_ticks=4_000, window_ticks=2_000, seeds=[1, 2],
+             load_ref_gbps=[{0: 12.0, 1: 20.0}] * 2,
+             sim_kwargs=dict(k_grant=8, k_srv=8, k_eg=8))
+    assert ctrl.stats["srv_fast_share"] == 1.0
+    assert 0.0 < ctrl.stats["grant_fast_share"] <= 1.0

@@ -6,7 +6,9 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import baselines, engine, token_bucket as tb
+import jax
+
+from repro.core import baselines, engine, sim, token_bucket as tb
 from repro.core.accelerator import CATALOG, AccelTable
 from repro.core.flow import SLO, FlowSet, FlowSpec, Path, TrafficPattern
 from repro.core.interconnect import ARB_PRIORITY, LinkSpec
@@ -301,3 +303,145 @@ def test_donated_carry_not_reused_by_engine():
     # would raise on a deleted (donated) buffer
     assert int(np.asarray(tbs.tokens).sum()) >= 0
     simulate(flows, accels, link, cfg, tbs, *arr)
+
+
+# ---------------------------------------------------------------------------
+# Fleet-wide branch choice of the batched tick (engine._fast_or_fallback)
+# ---------------------------------------------------------------------------
+
+
+def _fig11a_fleet(B=8, n_ticks=2_000):
+    """The Fig. 11(a) inline-NIC servers: SHA1-HMAC + AES-128-CBC (100 ns
+    overhead, so a service never ends within an 8-cycle tick), two MICA
+    users and a migration stream, stage widths 8."""
+    specs = [FlowSpec(0, 0, Path.INLINE_NIC_RX, 0,
+                      TrafficPattern(64, load=0.3, process="poisson"),
+                      SLO.gbps(2.0)),
+             FlowSpec(1, 1, Path.INLINE_NIC_RX, 1,
+                      TrafficPattern(256, load=0.3, process="poisson"),
+                      SLO.gbps(4.0)),
+             FlowSpec(2, 2, Path.INLINE_NIC_TX, 1,
+                      TrafficPattern(1500, load=0.9, process="onoff"),
+                      SLO.gbps(1.0), weight=0.05)]
+    flows = FlowSet.build(specs)
+    cfg = SimConfig(n_ticks=n_ticks, k_grant=8, k_srv=8, k_eg=8)
+    accels = AccelTable.build([CATALOG["sha1_hmac"], CATALOG["aes128_cbc"]])
+    arrs = [gen_arrivals(flows, cfg, seed=s,
+                         load_ref_gbps={0: 12.0, 1: 20.0, 2: 36.0})
+            for s in range(B)]
+    tbs = tb.pack([tb.params_for_gbps(g) for g in (2.0, 4.0, 1.0)])
+    return flows, [accels] * B, cfg, arrs, [tbs] * B
+
+
+def _chaining_fleet(names, n_ticks=2_000):
+    """One server per accelerator name, at 64-cycle ticks: synthetic50
+    serves a 1 KiB message in about 41 cycles, so its lanes chain within a
+    tick; sha1_hmac takes over 64 cycles and never chains."""
+    flows, cfg, _, tbs = _ragged_scenario(4, n_ticks=n_ticks)
+    cfg = dataclasses.replace(cfg, tick_cycles=64, k_srv=8, k_eg=8)
+    arrs = [gen_arrivals(flows, cfg, seed=s,
+                         load_ref_gbps={i: 50.0 for i in range(4)})
+            for s in range(len(names))]
+    accels = [AccelTable.build([CATALOG[n]]) for n in names]
+    return flows, accels, cfg, arrs, [tbs] * len(names)
+
+
+def _window_results(raw, cfg, n_flows):
+    host = jax.device_get({k: raw[k] for k in
+                           sim._RESULT_KEYS + engine.FAST_TICK_KEYS})
+    fast = {k: np.atleast_1d(host.pop(k)) for k in engine.FAST_TICK_KEYS}
+    els = ([host] if np.ndim(host["comp_n"]) == 0 else
+           [{k: v[b] for k, v in host.items()}
+            for b in range(len(host["comp_n"]))])
+    res = []
+    for el in els:
+        for k in sim._PER_FLOW_KEYS:
+            el[k] = el[k][:n_flows]
+        res.append(sim._collect_result(el, cfg, 0))
+    return res, fast
+
+
+@pytest.mark.parametrize("fleet", ["all_fast", "none_fast", "mixed"])
+def test_fleet_branch_choice_batch_matches_serial(fleet):
+    """The batched tick runs a stage's vectorized path alone on ticks where
+    every server qualifies and each server's own branch otherwise: results
+    stay bitwise those of serial runs, and the fast-tick counters count
+    fleet-uniform ticks (batched) or the server's own fast ticks (serial)."""
+    if fleet == "all_fast":
+        flows, accels, cfg, arrs, tbss = _fig11a_fleet(B=4)
+    elif fleet == "none_fast":
+        flows, accels, cfg, arrs, tbss = _chaining_fleet(["synthetic50"] * 3)
+    else:
+        flows, accels, cfg, arrs, tbss = _chaining_fleet(
+            ["synthetic50", "sha1_hmac", "sha1_hmac"])
+    link = LinkSpec()
+    n = cfg.n_ticks
+    arr_t, arr_sz = stack_arrivals(arrs)   # one trace shape: one compile
+    batch, b_fast = _window_results(
+        engine.run_window_batch(flows, accels, link, cfg, tbss,
+                                arr_t, arr_sz), cfg, flows.n)
+    serial, s_fast = [], {k: [] for k in engine.FAST_TICK_KEYS}
+    for b, (a, t) in enumerate(zip(accels, tbss)):
+        (r,), f = _window_results(
+            engine.run_window(flows, a, link, cfg, t, arr_t[b], arr_sz[b]),
+            cfg, flows.n)
+        serial.append(r)
+        for k in engine.FAST_TICK_KEYS:
+            s_fast[k].append(int(f[k][0]))
+    for b, (s, r) in enumerate(zip(serial, batch)):
+        _assert_results_equal(s, r, label=f"{fleet} server {b}")
+
+    for k in engine.FAST_TICK_KEYS:
+        got = b_fast[k]
+        # one fleet-wide choice per tick: every server reads the same count
+        assert (got == got[0]).all(), (k, got)
+        # a fleet-uniform tick is fast on every server, and each server's
+        # slow ticks can rule out at most that many fleet-uniform ones
+        assert got[0] <= min(s_fast[k]), (k, got, s_fast[k])
+        assert got[0] >= n - sum(n - c for c in s_fast[k]), (k, got, s_fast)
+    srv = "c_srv_fast_ticks"
+    if fleet == "all_fast":
+        assert b_fast[srv][0] == n and s_fast[srv] == [n] * len(arrs)
+    else:
+        assert b_fast[srv][0] < n
+    if fleet == "none_fast":
+        assert all(c < n for c in s_fast[srv]), s_fast[srv]
+    if fleet == "mixed":
+        # only server 0 chains: the fleet's uniform ticks are exactly the
+        # ticks its own predicate was true
+        assert s_fast[srv][1:] == [n] * (len(arrs) - 1), s_fast[srv]
+        assert b_fast[srv][0] == s_fast[srv][0] < n
+
+
+def _conds_by_stage(jaxpr, found=None):
+    """(stage, index aval) of every cond in a jaxpr, sub-jaxprs included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            stack = str(eqn.source_info.name_stack)
+            stage = next((s for s in ("grant", "service") if s in stack),
+                         None)
+            found.append((stage, eqn.invars[0].aval))
+        for p in eqn.params.values():
+            for q in (p if isinstance(p, (list, tuple)) else [p]):
+                if hasattr(q, "jaxpr") and hasattr(q, "consts"):
+                    _conds_by_stage(q.jaxpr, found)
+                elif hasattr(q, "eqns"):
+                    _conds_by_stage(q, found)
+    return found
+
+
+def test_batched_tick_keeps_unbatched_stage_conds():
+    """At the Fig. 11(a) cell's shape (B=8, three lanes, two accelerators,
+    stage widths 8) the batched window program keeps a real conditional
+    for the grant and the service stage: its index is one scalar for the
+    fleet, so the sequential fallbacks do not run beside the vectorized
+    paths on every tick (a batched index would lower to a select)."""
+    flows, accels, cfg, arrs, tbss = _fig11a_fleet(B=8, n_ticks=1_500)
+    closed = jax.make_jaxpr(lambda: engine.run_window_batch(
+        flows, accels, LinkSpec(), cfg, tbss, *stack_arrivals(arrs)))()
+    conds = _conds_by_stage(closed.jaxpr)
+    for stage in ("grant", "service"):
+        idx = [aval for s, aval in conds if s == stage]
+        assert idx, (stage, conds)
+        assert all(a.shape == () for a in idx), (stage, idx)
