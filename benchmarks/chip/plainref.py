@@ -12,9 +12,13 @@ accelerators, link, tenants and their SLOs come from the configuration
 file, the traces from the benchmark's own generator (``tracegen.py``), and
 the token-bucket registers from ``plan_registers``, the paper's recipe
 (Bkt_Size fixed, Refill_Rate / Interval swept to the SLO) computed here.
-Between windows it applies the configuration's control rule (Arcus
-Algorithm 1: a violated tenant's headroom widens and its registers are
-planned again) to its own measured rates.
+A tenant whose messages are large for its accelerator (``split_bytes``) is
+replayed as the paper's message re-sizing makes it: every message split
+into chunks at its arrival time, under a bucket of four chunks; the program
+receives the whole messages and has to split them itself.  Between windows
+it applies the configuration's control rule (Arcus Algorithm 1: a violated
+tenant's headroom widens and its registers are planned again) to its own
+measured rates.
 
 The controller's decisions in a timeline are the lane layout and the
 registers it writes; ``check`` holds both against the reference's, and
@@ -362,19 +366,46 @@ def _optimal_msg_bytes(acc: dict) -> int:
     return int(good.min()) if len(good) else int(grid[-1])
 
 
+def split_bytes(config: dict, complement: list[str], tenant: dict
+                ) -> int | None:
+    """The chunk size a tenant's messages are split to, or None: a message
+    over four chunks of twice the accelerator's optimal size is split
+    into chunks of that size (the paper's message re-sizing, use case 1)."""
+    acc = config["accelerators"][complement[tenant["accel"]]]
+    chunk = 2 * _optimal_msg_bytes(acc)
+    return chunk if int(tenant["msg_bytes"]) > 4 * chunk else None
+
+
+def split_trace(times, sizes, chunk: int) -> tuple[list[int], list[int]]:
+    """One lane's arrivals with every message over ``chunk`` bytes split
+    into chunks of ``chunk`` bytes and a remainder, each chunk at its
+    message's arrival time, in stable time order; padding (size 0) is
+    dropped."""
+    out = []
+    for t, s in zip(np.asarray(times).tolist(), np.asarray(sizes).tolist()):
+        n = -(-s // chunk)
+        out += [(t, min(chunk, s - j * chunk)) for j in range(n)]
+    out.sort(key=lambda ts: ts[0])
+    return [t for t, _s in out], [s for _t, s in out]
+
+
 def plan_registers(config: dict, complement: list[str], tenant: dict,
                    headroom: float = 1.0) -> tuple[int, int, int, int]:
     """The (refill, bkt, interval, mode) registers that shape a tenant at
     its Gbps SLO times ``headroom`` on its accelerator (ingress rate: the
-    SLO, or SLO / R for an expanding accelerator)."""
+    SLO, or SLO / R for an expanding accelerator).  A split tenant's bucket
+    holds four chunks (at least one refill), so its chunks are paced
+    smoothly rather than in bursts of whole messages."""
     acc = config["accelerators"][complement[tenant["accel"]]]
-    if tenant["msg_bytes"] > 8 * _optimal_msg_bytes(acc):
-        raise ValueError("split messages have no reference model")
     gbps = float(tenant["slo_gbps"])
     if acc["r_kind"] == "expand":
         gbps /= max(acc["r_value"], 1e-6)
-    return _gbps_registers(gbps * headroom, float(config["clock_hz"])) \
-        + (MODE_GBPS,)
+    refill, bkt, interval = _gbps_registers(gbps * headroom,
+                                            float(config["clock_hz"]))
+    chunk = split_bytes(config, complement, tenant)
+    if chunk is not None:
+        bkt = max(refill, 4 * chunk)
+    return refill, bkt, interval, MODE_GBPS
 
 
 def plan(cell, b: int) -> tuple[list[dict], list[dict]]:
@@ -438,9 +469,15 @@ def replay(cell, b: int, variant: str = "exact") -> dict:
     tenants, lanes = plan(cell, b)
     width = len(lanes)
     times, sizes = cell.traces[b]
-    ref = ServerRef(cfg, comp, width, np.unique(sizes).tolist(), variant)
-    for i in range(width):
-        ref.set_trace(i, times[i], sizes[i])
+    lane_traces = []
+    for i, t in enumerate(tenants):
+        chunk = split_bytes(cfg, comp, t)
+        lane_traces.append((times[i], sizes[i]) if chunk is None
+                           else split_trace(times[i], sizes[i], chunk))
+    lane_sizes = np.concatenate([np.asarray(s) for _t, s in lane_traces])
+    ref = ServerRef(cfg, comp, width, np.unique(lane_sizes).tolist(), variant)
+    for i, (lt, ls) in enumerate(lane_traces):
+        ref.set_trace(i, lt, ls)
     ref.set_lanes(lanes)
     headroom = [1.0] * width
     regs = [plan_registers(cfg, comp, t) for t in tenants]

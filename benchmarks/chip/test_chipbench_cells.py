@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -19,7 +20,9 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 
 import plainref  # noqa: E402
+from fleetcell import load_json  # noqa: E402
 import run  # noqa: E402
+import tracegen  # noqa: E402
 
 TINY = {
     "mica8.fig11a": dict(servers=2, profile_ticks=1000, window_ticks=300,
@@ -175,6 +178,126 @@ def test_control_rule_acts(prepared):
     registers after the first window: the fault above has work to skip."""
     rep = plainref.replay(prepared["cell"], 1)
     assert any(rep["wrote"][1:])
+
+
+# ---------------------------------------------------------------------------
+# A split deployment: large messages that the paper's re-sizing splits
+# ---------------------------------------------------------------------------
+
+def _split_overrides() -> dict:
+    """``mica8.fig11a`` turned into a deployment of one ``aes256`` engine
+    a server, with a 4 KiB stream beside a 64 KiB one (over ``aes256``'s
+    split threshold of 16 KiB), both ``FUNCTION_CALL`` at 8 Gbps."""
+    from repro.core.accelerator import CATALOG
+    keys = next(iter(load_json("configs", "mica-kv-crypto-b8.json")
+                     ["accelerators"].values()))
+
+    def tenant(fid, cls, msg, load_ref):
+        return {"class": cls, "flow_id": fid, "accel": 0,
+                "path": "FUNCTION_CALL", "msg_bytes": msg, "load": 0.5,
+                "load_ref_gbps": load_ref, "slo_gbps": 8.0, "priority": 1,
+                "weight": 1.0}
+    return dict(
+        servers=2, profile_ticks=2000, window_ticks=1000, windows=6,
+        complements=[["aes256"]],
+        accelerators={"aes256": {k: getattr(CATALOG["aes256"], k)
+                                 for k in keys}},
+        tenants=[tenant(0, "stream", 4096, 20.0),
+                 tenant(1, "large", 65536, 40.0)])
+
+
+@pytest.fixture(scope="module")
+def split_prepared():
+    return run.setup("mica8.fig11a", 2**31 + 23, require_chip=False,
+                     overrides=_split_overrides())
+
+
+def _fed_in_chunks(cell, scale: int = 1) -> list:
+    """Each server's traces split as the program's own shaper splits
+    them (``reshape_decision``'s size, times ``scale``, by
+    ``reshape_trace``): the stand-in for the split that the program's
+    normal path does not make yet."""
+    from repro.core.flow import SLO
+    from repro.core.shaper import reshape_decision, reshape_trace
+    accels, _link, _flow_spec = cell._program_objects()
+    fed = []
+    for b in range(cell.B):
+        times, sizes = cell.traces[b]
+        rows = []
+        for i, (t, _p) in enumerate(cell.specs[b]):
+            acc = accels[cell.complement(b)[t["accel"]]]
+            chunk = reshape_decision(acc, SLO.gbps(t["slo_gbps"]),
+                                     t["msg_bytes"]).resize_to
+            rows.append((times[i], sizes[i]) if chunk is None
+                        else reshape_trace(times[i], sizes[i], scale * chunk))
+        m = max(len(r[0]) for r in rows)
+        arr_t = np.full((len(rows), m), tracegen.INF_I32, np.int32)
+        arr_s = np.zeros((len(rows), m), np.int32)
+        for i, (t, sz) in enumerate(rows):
+            arr_t[i, :len(t)], arr_s[i, :len(sz)] = t, sz
+        fed.append((arr_t, arr_s))
+    return fed
+
+
+def _feed(monkeypatch, fed) -> None:
+    """Every ``FleetController.run`` gets ``fed`` as its arrivals."""
+    from repro.core.controller import FleetController
+    inner = FleetController.run
+
+    def run_fed(self, **kw):
+        return inner(self, **dict(kw, arrivals=fed))
+    monkeypatch.setattr(FleetController, "run", run_fed)
+
+
+def test_split_deployment_matches(split_prepared, monkeypatch):
+    """Fed the chunks its shaper decides, the program agrees with the
+    reference's replay of the whole messages on every number, and the
+    split lane did real work under the bucket of four chunks."""
+    cell = split_prepared["cell"]
+    _feed(monkeypatch, _fed_in_chunks(cell))
+    res = _measure(split_prepared)
+    assert res["correct"] is True, res["checked"]
+    assert all(v["value"] == 0 for v in res["checked"].values())
+    rep = plainref.replay(cell, 0)
+    assert rep["registers"][0][1][1] == 4 * 4096
+    assert rep["counters"]["c_done_msgs"][1] > 2 * 16
+
+
+def _whole_messages(monkeypatch, cell):
+    """The program's normal path today: whole messages, never split."""
+
+
+def _double_chunks(monkeypatch, cell):
+    """Chunks of twice the decided size."""
+    _feed(monkeypatch, _fed_in_chunks(cell, scale=2))
+
+
+def _chunk_bucket_dropped(monkeypatch, cell):
+    """Split arrivals, but admission and re-planning write the registers
+    of an unsplit tenant (no bucket of four chunks)."""
+    from repro.core import runtime
+    _feed(monkeypatch, _fed_in_chunks(cell))
+    inner = runtime.reshape_decision
+
+    def plan(accel, slo, msg_bytes, **kw):
+        d = inner(accel, slo, msg_bytes, **kw)
+        if d.resize_to is None:
+            return d
+        return dataclasses.replace(
+            d, params=inner(accel, slo, d.resize_to, **kw).params)
+    monkeypatch.setattr(runtime, "reshape_decision", plan)
+
+
+@pytest.mark.parametrize("fault", [_whole_messages, _double_chunks,
+                                   _chunk_bucket_dropped],
+                         ids=["whole_messages", "double_chunks",
+                              "chunk_bucket_dropped"])
+def test_split_fault_is_not_correct(split_prepared, monkeypatch, fault):
+    fault(monkeypatch, split_prepared["cell"])
+    res = _measure(split_prepared)
+    assert res["correct"] is False, res["checked"]
+    if fault is _chunk_bucket_dropped:
+        assert res["checked"]["plan_differ"]["value"] > 0
 
 
 def test_exits_without_a_chip():
